@@ -36,26 +36,31 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.kernels.flash_attention.common import NEG_INF, block_size, vmem
-from repro.quant.core import unpack_int4
+from repro.quant.core import int4_planes
 
 
 def _decode_mask(qpos_ref, kvpos_ref, window: int):
     """(1, bk) valid+causal(+window) mask from explicit positions."""
-    qp = qpos_ref[0, 0]                               # scalar int32
-    kp = kvpos_ref[...]                               # (1, bk)
+    qp = qpos_ref[0, 0, 0]                            # scalar int32
+    kp = kvpos_ref[0]                                 # (1, bk)
     mask = (kp >= 0) & (kp <= qp)                     # valid + causal
     if window:
         mask &= qp - kp < window
     return mask
 
 
-def _online_update(q, k, v, mask, m_scr, l_scr, acc_scr, *,
+def _online_update(qs, ks, vs, mask, m_scr, l_scr, acc_scr, *,
                    scale: float, softcap: float):
     """One K/V block of the online-softmax sweep (shared by the fp and
-    quantised-KV decode kernels; operands already dequantised f32)."""
-    s = jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32) * scale   # (rep, bk)
+    quantised-KV decode kernels; operands already dequantised f32).
+
+    Operands come as matching planes of the head dim: the score sums the
+    plane-wise ``q · k`` contractions, and value plane ``i`` accumulates
+    into ``acc_scr[i]``.  fp and int8 pass one plane; int4 passes the even
+    and odd halves (see :func:`repro.quant.core.int4_planes`)."""
+    s = sum(jax.lax.dot_general(
+        q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
+        for q, k in zip(qs, ks)) * scale              # (rep, bk)
     if softcap:
         s = softcap * jnp.tanh(s / softcap)
     s = jnp.where(mask, s, NEG_INF)                   # (1,bk) -> (rep,bk)
@@ -65,24 +70,17 @@ def _online_update(q, k, v, mask, m_scr, l_scr, acc_scr, *,
     alpha = jnp.exp(m_prev - m_new)
     p = jnp.exp(s - m_new)
     l_scr[...] = l_scr[...] * alpha + p.sum(axis=-1, keepdims=True)
-    acc_scr[...] = acc_scr[...] * alpha + jax.lax.dot_general(
-        p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+    for i, v in enumerate(vs):
+        acc_scr[i] = acc_scr[i] * alpha + jax.lax.dot_general(
+            p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
     m_scr[...] = m_new
 
 
-def _decode_kernel(
-    q_ref,                        # (1, 1, rep, hd)
-    k_ref,                        # (1, 1, bk, hd)
-    v_ref,                        # (1, 1, bk, hdv)
-    qpos_ref,                     # (1, 1)
-    kvpos_ref,                    # (1, bk)
-    o_ref,                        # (1, 1, rep, hdv)
-    m_scr, l_scr, acc_scr,        # VMEM scratch: (rep,1), (rep,1), (rep,hdv)
-    *,
-    scale: float,
-    window: int,
-    softcap: float,
-):
+def _sweep(body, qpos_ref, kvpos_ref, o_ref, m_scr, l_scr, acc_scr, *,
+           window: int):
+    """The K/V-axis skeleton both decode kernels share: reset the softmax
+    state on the first block, run ``body(mask)`` on blocks with at least
+    one attendable entry, normalise into ``o_ref`` on the last."""
     ik = pl.program_id(2)
     nk = pl.num_programs(2)
 
@@ -97,17 +95,57 @@ def _decode_kernel(
     # whole block masked (empty slot / outside the window) -> skip the MXU
     @pl.when(jnp.any(mask))
     def _body():
-        q = q_ref[0, 0].astype(jnp.float32)           # (rep, hd)
-        k = k_ref[0, 0].astype(jnp.float32)           # (bk, hd)
-        v = v_ref[0, 0].astype(jnp.float32)           # (bk, hdv)
-        _online_update(q, k, v, mask, m_scr, l_scr, acc_scr,
-                       scale=scale, softcap=softcap)
+        body(mask)
 
     @pl.when(ik == nk - 1)
     def _finalize():
         l = l_scr[...]
         l = jnp.where(l == 0.0, 1.0, l)               # empty slot -> zeros
-        o_ref[0, 0] = (acc_scr[...] / l).astype(o_ref.dtype)
+        for i in range(acc_scr.shape[0]):
+            o_ref[0, 0, i] = (acc_scr[i] / l).astype(o_ref.dtype)
+
+
+def _decode_kernel(
+    q_ref,                        # (1, 1, rep, hd)
+    k_ref,                        # (1, 1, bk, hd)
+    v_ref,                        # (1, 1, bk, hdv)
+    qpos_ref,                     # (1, 1, 1)
+    kvpos_ref,                    # (1, 1, bk)
+    o_ref,                        # (1, 1, 1, rep, hdv)
+    m_scr, l_scr, acc_scr,        # VMEM scratch: (rep,1), (rep,1), (1,rep,hdv)
+    *,
+    scale: float,
+    window: int,
+    softcap: float,
+):
+    def body(mask):
+        q = q_ref[0, 0].astype(jnp.float32)           # (rep, hd)
+        k = k_ref[0, 0].astype(jnp.float32)           # (bk, hd)
+        v = v_ref[0, 0].astype(jnp.float32)           # (bk, hdv)
+        _online_update((q,), (k,), (v,), mask, m_scr, l_scr, acc_scr,
+                       scale=scale, softcap=softcap)
+
+    _sweep(body, qpos_ref, kvpos_ref, o_ref, m_scr, l_scr, acc_scr,
+           window=window)
+
+
+def _positions(q_pos, kv_pos, B, Skv):
+    """Per-slot positions as ``(B, 1, 1)`` / ``(B, 1, Skv)``: a block's last
+    two dims must be multiples of (8, 128) or span the array, which a
+    ``(1, ·)`` block of a ``(B, ·)`` array breaks on the TPU whenever B > 1."""
+    return (q_pos.astype(jnp.int32).reshape(B, 1, 1),
+            kv_pos.astype(jnp.int32).reshape(B, 1, Skv))
+
+
+def _pos_specs(bk):
+    return [pl.BlockSpec((1, 1, 1), lambda b, h, ik: (b, 0, 0)),
+            pl.BlockSpec((1, 1, bk), lambda b, h, ik: (b, 0, ik))]
+
+
+def _planes_out(out, B, Hq, hdv):
+    """``(B, Hkv, P, rep, hdv/P)`` kernel output -> ``(B, 1, Hq, hdv)``,
+    re-interleaving the P head-dim planes (P = 2 for int4 values)."""
+    return out.transpose(0, 1, 3, 4, 2).reshape(B, 1, Hq, hdv)
 
 
 def flash_decode_fwd(
@@ -139,8 +177,7 @@ def flash_decode_fwd(
     qf = q[:, 0].reshape(B, Hkv, rep, hd)
     kt = k.transpose(0, 2, 1, 3)                  # (B, Hkv, Skv, hd)
     vt = v.transpose(0, 2, 1, 3)                  # (B, Hkv, Skv, hdv)
-    qp = q_pos.astype(jnp.int32).reshape(B, 1)
-    kp = kv_pos.astype(jnp.int32)
+    qp, kp = _positions(q_pos, kv_pos, B, Skv)
 
     grid = (B, Hkv, Skv // bk)
     kern = functools.partial(
@@ -153,20 +190,20 @@ def flash_decode_fwd(
             pl.BlockSpec((1, 1, rep, hd), lambda b, h, ik: (b, h, 0, 0)),
             pl.BlockSpec((1, 1, bk, hd), lambda b, h, ik: (b, h, ik, 0)),
             pl.BlockSpec((1, 1, bk, hdv), lambda b, h, ik: (b, h, ik, 0)),
-            pl.BlockSpec((1, 1), lambda b, h, ik: (b, 0)),
-            pl.BlockSpec((1, bk), lambda b, h, ik: (b, ik)),
+            *_pos_specs(bk),
         ],
-        out_specs=pl.BlockSpec((1, 1, rep, hdv), lambda b, h, ik: (b, h, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, Hkv, rep, hdv), q.dtype),
+        out_specs=pl.BlockSpec((1, 1, 1, rep, hdv),
+                               lambda b, h, ik: (b, h, 0, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((B, Hkv, 1, rep, hdv), q.dtype),
         scratch_shapes=[
             vmem((rep, 1)),
             vmem((rep, 1)),
-            vmem((rep, hdv)),
+            vmem((1, rep, hdv)),
         ],
         interpret=interpret,
     )(qf, kt, vt, qp, kp)
 
-    return out.reshape(B, 1, Hq, hdv)
+    return _planes_out(out, B, Hq, hdv)
 
 
 # ---------------------------------------------------------------------------
@@ -174,53 +211,45 @@ def flash_decode_fwd(
 # ---------------------------------------------------------------------------
 
 def _decode_quant_kernel(
-    q_ref,                        # (1, 1, rep, hd)
-    kq_ref,                       # (1, 1, bk, hd')  int8 codes (hd' = hd/pack)
-    ks_ref,                       # (1, 1, bk, 1)    f32 per-(entry, head)
-    vq_ref,                       # (1, 1, bk, hdv')
-    vs_ref,                       # (1, 1, bk, 1)
-    qpos_ref,                     # (1, 1)
-    kvpos_ref,                    # (1, bk)
-    o_ref,                        # (1, 1, rep, hdv)
-    m_scr, l_scr, acc_scr,
-    *,
+    *refs,
     scale: float,
     window: int,
     softcap: float,
     kv_bits: int,
 ):
-    ik = pl.program_id(2)
-    nk = pl.num_programs(2)
+    # int8: q (1,1,rep,hd); int4: q split into even / odd head-dim halves,
+    # each (1,1,rep,hd/2), matching the low / high nibble planes of the codes
+    nq = 2 if kv_bits == 4 else 1
+    q_refs = refs[:nq]
+    (kq_ref,                      # (1, 1, bk, hd')  int8 codes (hd' = hd/pack)
+     ks_ref,                      # (1, 1, bk, 1)    f32 per-(entry, head)
+     vq_ref,                      # (1, 1, bk, hdv')
+     vs_ref,                      # (1, 1, bk, 1)
+     qpos_ref,                    # (1, 1, 1)
+     kvpos_ref,                   # (1, 1, bk)
+     o_ref,                       # (1, 1, P, rep, hdv/P)
+     m_scr, l_scr, acc_scr) = refs[nq:]
 
-    @pl.when(ik == 0)
-    def _init():
-        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[...] = jnp.zeros_like(l_scr)
-        acc_scr[...] = jnp.zeros_like(acc_scr)
-
-    mask = _decode_mask(qpos_ref, kvpos_ref, window)
-
-    @pl.when(jnp.any(mask))
-    def _body():
-        q = q_ref[0, 0].astype(jnp.float32)           # (rep, hd)
+    def body(mask):
+        qs = tuple(r[0, 0].astype(jnp.float32) for r in q_refs)
         kq = kq_ref[0, 0]                             # (bk, hd') int8
         vq = vq_ref[0, 0]
         if kv_bits == 4:
-            # adjacent-pair nibble unpack along the head dim — the packing
+            # adjacent-pair nibble planes along the head dim — the packing
             # contract of repro.quant.core (single source of truth)
-            kq = unpack_int4(kq, axis=-1)
-            vq = unpack_int4(vq, axis=-1)
+            kq, vq = int4_planes(kq), int4_planes(vq)
+        else:
+            kq, vq = (kq,), (vq,)
         # in-VMEM dequant: the pool streams HBM→VMEM at 1 or 0.5 B/element
-        k = kq.astype(jnp.float32) * ks_ref[0, 0].astype(jnp.float32)
-        v = vq.astype(jnp.float32) * vs_ref[0, 0].astype(jnp.float32)
-        _online_update(q, k, v, mask, m_scr, l_scr, acc_scr,
+        ks = ks_ref[0, 0].astype(jnp.float32)
+        vs = vs_ref[0, 0].astype(jnp.float32)
+        k = tuple(c.astype(jnp.float32) * ks for c in kq)
+        v = tuple(c.astype(jnp.float32) * vs for c in vq)
+        _online_update(qs, k, v, mask, m_scr, l_scr, acc_scr,
                        scale=scale, softcap=softcap)
 
-    @pl.when(ik == nk - 1)
-    def _finalize():
-        l = l_scr[...]
-        l = jnp.where(l == 0.0, 1.0, l)               # empty slot -> zeros
-        o_ref[0, 0] = (acc_scr[...] / l).astype(o_ref.dtype)
+    _sweep(body, qpos_ref, kvpos_ref, o_ref, m_scr, l_scr, acc_scr,
+           window=window)
 
 
 def flash_decode_quant_fwd(
@@ -249,7 +278,8 @@ def flash_decode_quant_fwd(
     pack = 2 if kv_bits == 4 else 1
     B, Sq, Hq, hd = q.shape
     _, Skv, Hkv, hdq = k_q.shape
-    hdv = v_q.shape[-1] * pack
+    hdvq = v_q.shape[-1]
+    hdv = hdvq * pack
     if Sq != 1:
         raise ValueError(f"decode kernel needs Sq == 1, got {Sq}")
     if hdq * pack != hd:
@@ -263,12 +293,13 @@ def flash_decode_quant_fwd(
         raise ValueError(f"block size ({bk}) must divide Skv ({Skv})")
 
     qf = q[:, 0].reshape(B, Hkv, rep, hd)
+    # int4: even / odd head dims meet the low / high nibble planes
+    qs = (qf[..., 0::2], qf[..., 1::2]) if pack == 2 else (qf,)
     kqt = k_q.transpose(0, 2, 1, 3)               # (B, Hkv, Skv, hd')
     vqt = v_q.transpose(0, 2, 1, 3)
     kst = k_s.transpose(0, 2, 1)[..., None].astype(jnp.float32)
     vst = v_s.transpose(0, 2, 1)[..., None].astype(jnp.float32)
-    qp = q_pos.astype(jnp.int32).reshape(B, 1)
-    kp = kv_pos.astype(jnp.int32)
+    qp, kp = _positions(q_pos, kv_pos, B, Skv)
 
     grid = (B, Hkv, Skv // bk)
     kern = functools.partial(
@@ -279,23 +310,23 @@ def flash_decode_quant_fwd(
         kern,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, 1, rep, hd), lambda b, h, ik: (b, h, 0, 0)),
+            *[pl.BlockSpec((1, 1, rep, hdq), lambda b, h, ik: (b, h, 0, 0))
+              for _ in qs],
             pl.BlockSpec((1, 1, bk, hdq), lambda b, h, ik: (b, h, ik, 0)),
             pl.BlockSpec((1, 1, bk, 1), lambda b, h, ik: (b, h, ik, 0)),
-            pl.BlockSpec((1, 1, bk, v_q.shape[-1]),
-                         lambda b, h, ik: (b, h, ik, 0)),
+            pl.BlockSpec((1, 1, bk, hdvq), lambda b, h, ik: (b, h, ik, 0)),
             pl.BlockSpec((1, 1, bk, 1), lambda b, h, ik: (b, h, ik, 0)),
-            pl.BlockSpec((1, 1), lambda b, h, ik: (b, 0)),
-            pl.BlockSpec((1, bk), lambda b, h, ik: (b, ik)),
+            *_pos_specs(bk),
         ],
-        out_specs=pl.BlockSpec((1, 1, rep, hdv), lambda b, h, ik: (b, h, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, Hkv, rep, hdv), q.dtype),
+        out_specs=pl.BlockSpec((1, 1, pack, rep, hdvq),
+                               lambda b, h, ik: (b, h, 0, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((B, Hkv, pack, rep, hdvq), q.dtype),
         scratch_shapes=[
             vmem((rep, 1)),
             vmem((rep, 1)),
-            vmem((rep, hdv)),
+            vmem((pack, rep, hdvq)),
         ],
         interpret=interpret,
-    )(qf, kqt, kst, vqt, vst, qp, kp)
+    )(*qs, kqt, kst, vqt, vst, qp, kp)
 
-    return out.reshape(B, 1, Hq, hdv)
+    return _planes_out(out, B, Hq, hdv)

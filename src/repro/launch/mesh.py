@@ -22,11 +22,9 @@ from repro.core.sfc import curve_positions
 
 
 def _mesh_kwargs(n):
-    """`axis_types` appeared after jax 0.4.x — pass it only when present
-    (Auto is the default behaviour on older versions anyway)."""
-    if hasattr(jax.sharding, "AxisType"):
-        return {"axis_types": (jax.sharding.AxisType.Auto,) * n}
-    return {}
+    """Every mesh axis is Auto: GSPMD propagates shardings from the plan's
+    constraints (the model code names no explicit axes)."""
+    return {"axis_types": (jax.sharding.AxisType.Auto,) * n}
 
 
 def make_production_mesh(*, multi_pod: bool = False, sfc_order: str = "") -> Mesh:
@@ -57,7 +55,8 @@ def sfc_device_order(shape, curve: str = "boustrophedon") -> np.ndarray:
 
 
 def small_mesh(data: int = 2, model: int = 2) -> Mesh:
-    """Tiny mesh for CPU integration tests (requires forced host devices)."""
+    """A ``(data, model)`` mesh over the first ``data * model`` devices of
+    ``jax.devices()`` (chips, or forced host devices on the CPU)."""
     n = data * model
     return jax.make_mesh((data, model), ("data", "model"),
                          devices=jax.devices()[:n],

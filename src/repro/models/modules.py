@@ -104,20 +104,20 @@ def sinusoidal_positions(positions, dim: int):
 # MLP (dense FFN) — the paper's "static / ReRAM-macro" kernel class
 # ---------------------------------------------------------------------------
 
-def init_mlp(key, cfg, d_in=None, d_ff=None):
+def init_mlp(key, cfg, d_in=None, d_ff=None, *, dtype=jnp.float32):
     d = d_in or cfg.d_model
     f = d_ff or cfg.d_ff
     ks = jax.random.split(key, 3)
     p = {}
     if cfg.glu:
-        p["w_gate"] = dense_init(ks[0], (d, f), jnp.float32)
-        p["w_up"] = dense_init(ks[1], (d, f), jnp.float32)
+        p["w_gate"] = dense_init(ks[0], (d, f), dtype)
+        p["w_up"] = dense_init(ks[1], (d, f), dtype)
     else:
-        p["w_up"] = dense_init(ks[1], (d, f), jnp.float32)
-    p["w_down"] = dense_init(ks[2], (f, d), jnp.float32, fan_in=f)
+        p["w_up"] = dense_init(ks[1], (d, f), dtype)
+    p["w_down"] = dense_init(ks[2], (f, d), dtype, fan_in=f)
     if cfg.mlp_bias:
-        p["b_up"] = jnp.zeros((f,), jnp.float32)
-        p["b_down"] = jnp.zeros((d,), jnp.float32)
+        p["b_up"] = jnp.zeros((f,), dtype)
+        p["b_down"] = jnp.zeros((d,), dtype)
     return p
 
 

@@ -36,7 +36,7 @@ def init_moe(key, cfg, *, dtype=jnp.float32):
         import dataclasses
         shared_cfg = dataclasses.replace(cfg, glu=True, mlp_bias=False)
         p["shared"] = init_mlp(ks[4], shared_cfg,
-                               d_ff=cfg.n_shared_experts * Fe)
+                               d_ff=cfg.n_shared_experts * Fe, dtype=dtype)
     return p
 
 

@@ -78,14 +78,14 @@ def _init_layer(key, cfg, kind: str, use_moe: bool, *, causal: bool, dtype):
         p["ln1"] = M.init_norm(ks[0], cfg)
         p["rec"] = init_rglru(ks[1], cfg, dtype=dtype)
         p["ln2"] = M.init_norm(ks[2], cfg)
-        p["mlp"] = M.init_mlp(ks[3], cfg)
+        p["mlp"] = M.init_mlp(ks[3], cfg, dtype=dtype)
         return p
     if kind == "cross":  # vlm gated cross-attention layer
         p["ln1"] = M.init_norm(ks[0], cfg)
         p["attn"] = init_attention(ks[1], cfg, cross=True, dtype=dtype)
         p["gate_attn"] = jnp.zeros((), jnp.float32)
         p["ln2"] = M.init_norm(ks[2], cfg)
-        p["mlp"] = M.init_mlp(ks[3], cfg)
+        p["mlp"] = M.init_mlp(ks[3], cfg, dtype=dtype)
         p["gate_mlp"] = jnp.zeros((), jnp.float32)
         return p
     # global / local attention layer
@@ -95,7 +95,7 @@ def _init_layer(key, cfg, kind: str, use_moe: bool, *, causal: bool, dtype):
     else:
         p["attn"] = init_attention(ks[1], cfg, dtype=dtype)
     if cfg.parallel_block:
-        p["mlp"] = M.init_mlp(ks[3], cfg)
+        p["mlp"] = M.init_mlp(ks[3], cfg, dtype=dtype)
         return p
     if cfg.post_norm:
         p["ln1_post"] = M.init_norm(ks[4], cfg)
@@ -106,7 +106,7 @@ def _init_layer(key, cfg, kind: str, use_moe: bool, *, causal: bool, dtype):
     if use_moe:
         p["moe"] = init_moe(ks[3], cfg, dtype=dtype)
     else:
-        p["mlp"] = M.init_mlp(ks[3], cfg)
+        p["mlp"] = M.init_mlp(ks[3], cfg, dtype=dtype)
     if cfg.post_norm:
         p["ln2_post"] = M.init_norm(ks[7], cfg)
     return p

@@ -61,11 +61,22 @@ def pack_int4(codes: jax.Array, axis: int = -1) -> jax.Array:
     return jnp.moveaxis(packed.astype(jnp.int8), -1, axis)
 
 
+def int4_planes(packed: jax.Array) -> tuple[jax.Array, jax.Array]:
+    """Sign-extended ``(low, high)`` nibble planes of packed int4 codes, as
+    int32 of the packed shape: ``low[i]`` is code ``2i`` and ``high[i]``
+    code ``2i+1`` of the packing axis.  The Pallas kernels contract each
+    plane against the even / odd half of the other operand, which needs no
+    in-kernel interleave (a lane-splitting reshape Mosaic cannot lower)."""
+    p = packed.astype(jnp.int32)
+    lo = jnp.right_shift(jnp.left_shift(p, 28), 28)    # arithmetic: sign-ext
+    hi = jnp.right_shift(p, 4)
+    return lo, hi
+
+
 def unpack_int4(packed: jax.Array, axis: int = -1) -> jax.Array:
     """Inverse of :func:`pack_int4` — sign-extending nibble unpack."""
     p = jnp.moveaxis(packed, axis, -1)
-    lo = jnp.right_shift(jnp.left_shift(p, 4), 4)      # arithmetic: sign-ext
-    hi = jnp.right_shift(p, 4)
+    lo, hi = int4_planes(p)
     c = jnp.stack([lo, hi], axis=-1).reshape(p.shape[:-1] + (p.shape[-1] * 2,))
     return jnp.moveaxis(c.astype(jnp.int8), -1, axis)
 

@@ -11,8 +11,9 @@ per element, are dequantised in VMEM, and accumulate in fp32 on the MXU.
 Grid ``(M/bm, N/bn, K/bk)``; the trailing K axis is sequential on TPU so
 the fp32 accumulator lives in VMEM scratch across the K sweep.  For int4
 the code block is ``(bk/2, bn)`` — adjacent-pair packing along K keeps a
-contiguous packed block ↔ contiguous original rows, so the in-VMEM unpack
-is a local nibble split + row interleave.
+contiguous packed block ↔ contiguous original rows.  The kernel never
+interleaves the nibbles back into rows: the low / high planes contract
+against the even / odd K columns of ``x``, split outside the kernel.
 """
 from __future__ import annotations
 
@@ -22,7 +23,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.quant.core import unpack_int4
+from repro.quant.core import int4_planes
 
 
 def _vmem(shape):
@@ -31,25 +32,31 @@ def _vmem(shape):
     return pltpu.VMEM(shape, jnp.float32)
 
 
-def _qmm_kernel(x_ref, q_ref, s_ref, o_ref, acc_scr, *,
-                n_k: int, bits: int, group: int):
+def _qmm_kernel(*refs, n_k: int, bits: int, group: int):
+    # int8: x (bm, bk) against codes (bk, bn).  int4: x split into its even /
+    # odd K columns, each (bm, bk/2), against the low / high nibble planes
+    # of the packed (bk/2, bn) codes
+    nx = 2 if bits == 4 else 1
+    x_refs = refs[:nx]
+    q_ref, s_ref, o_ref, acc_scr = refs[nx:]
     ik = pl.program_id(2)
 
     @pl.when(ik == 0)
     def _init():
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    x = x_ref[...].astype(jnp.float32)               # (bm, bk)
     q = q_ref[...]                                   # int8 codes (packed?)
-    # adjacent-pair nibble unpack along K (repro.quant.core contract)
-    codes = unpack_int4(q, axis=0) if bits == 4 else q   # (bk, bn)
-    s = s_ref[...].astype(jnp.float32)               # (bk/g | 1, bn)
+    # adjacent-pair nibble planes along K (repro.quant.core contract)
+    planes = int4_planes(q) if bits == 4 else (q,)
+    s = s_ref[...].astype(jnp.float32)               # (rows/g | 1, bn)
     if group:
-        s = jnp.repeat(s, group, axis=0)             # (bk, bn)
-    w = codes.astype(jnp.float32) * s                # in-VMEM dequant
-    acc_scr[...] += jax.lax.dot_general(
-        x, w, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
+        s = jnp.repeat(s, group // nx, axis=0)       # (bk/nx, bn)
+    for x_ref, codes in zip(x_refs, planes):
+        x = x_ref[...].astype(jnp.float32)
+        w = codes.astype(jnp.float32) * s            # in-VMEM dequant
+        acc_scr[...] += jax.lax.dot_general(
+            x, w, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
 
     @pl.when(ik == n_k - 1)
     def _done():
@@ -73,17 +80,21 @@ def quant_matmul_pallas(x, q, scale, *, bits: int, group: int = 0,
     bm, bn, bk = min(bm, M), min(bn, N), min(bk, K)
     if M % bm or K % bk or N % bn:
         raise ValueError(f"dims {(M, K, N)} must divide blocks {(bm, bk, bn)}")
-    if group and bk % group:
-        raise ValueError(f"group {group} must divide the K block {bk}")
+    if group and (bk % group or group % pack):
+        raise ValueError(f"group {group} must divide the K block {bk}"
+                         + (" and be even at 4 bits" if pack == 2 else ""))
     n_k = K // bk
     sk = (bk // group) if group else 1               # scale rows per block
+    # int4: the even / odd K columns of x meet the low / high code planes
+    xs = (x[:, 0::2], x[:, 1::2]) if pack == 2 else (x,)
 
     grid = (M // bm, N // bn, n_k)
     return pl.pallas_call(
         functools.partial(_qmm_kernel, n_k=n_k, bits=bits, group=group),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((bm, bk), lambda i, j, k: (i, k)),
+            *[pl.BlockSpec((bm, bk // pack), lambda i, j, k: (i, k))
+              for _ in xs],
             pl.BlockSpec((bk // pack, bn), lambda i, j, k: (k, j)),
             pl.BlockSpec((sk, bn),
                          (lambda i, j, k: (k, j)) if group else
@@ -93,4 +104,4 @@ def quant_matmul_pallas(x, q, scale, *, bits: int, group: int = 0,
         out_shape=jax.ShapeDtypeStruct((M, N), x.dtype),
         scratch_shapes=[_vmem((bm, bn))],
         interpret=interpret,
-    )(x, q, scale)
+    )(*xs, q, scale)

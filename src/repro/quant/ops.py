@@ -20,8 +20,11 @@ def quant_matmul(x: jax.Array, qt: QuantTensor, *, impl: str = "auto"):
     """x (..., K) · dequant(qt (K, N)) -> (..., N), dtype follows x.
 
     impl: ref | pallas | pallas_interpret | auto (pallas on TPU, else ref).
-    Shapes the Pallas grid cannot tile exactly fall back to ref.
+    Under ``auto`` and ``pallas_interpret``, shapes the Pallas grid cannot
+    tile exactly fall back to ref; an explicit ``pallas`` raises instead,
+    so a compiled run never drops to the reference unseen.
     """
+    strict = impl == "pallas"
     if impl == "auto":
         impl = "pallas" if jax.default_backend() == "tpu" else "ref"
     if impl in ("pallas", "pallas_interpret"):
@@ -32,13 +35,18 @@ def quant_matmul(x: jax.Array, qt: QuantTensor, *, impl: str = "auto"):
         for d in lead:
             M *= d
         bm, bn, bk = min(128, M), min(256, N), min(512, K)
-        tiles = M % bm == 0 and N % bn == 0 and K % bk == 0 \
-            and (not qt.group or bk % qt.group == 0)
+        group_ok = not qt.group or (bk % qt.group == 0
+                                    and (qt.bits != 4 or qt.group % 2 == 0))
+        tiles = M % bm == 0 and N % bn == 0 and K % bk == 0 and group_ok
         if tiles and qt.q.ndim == 2:
             out = _kernel.quant_matmul_pallas(
                 x.reshape(M, K), qt.q, qt.scale, bits=qt.bits, group=qt.group,
                 bm=bm, bn=bn, bk=bk, interpret=impl == "pallas_interpret")
             return out.reshape(lead + (N,))
+        if strict:
+            raise ValueError(
+                f"no dequant-matmul kernel tiles x {x.shape} · codes "
+                f"{qt.q.shape} (bits={qt.bits}, group={qt.group})")
     return x @ dequantize(qt).astype(x.dtype)
 
 

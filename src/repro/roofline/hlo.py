@@ -354,9 +354,8 @@ _UTILIZATION_RE = re.compile(r"^utilization(\d+)\{\}$")
 def normalize_cost_analysis(ca) -> dict:
     """Normalise ``Compiled.cost_analysis()`` into a structured dict.
 
-    XLA's estimate arrives as a flat property map whose shape varies by
-    jax version and backend: ``None`` when the backend doesn't implement
-    it, a one-element list on older jax, and per-operand keys spelled
+    XLA's estimate arrives as a flat property map (``None`` when the
+    backend doesn't implement it) with per-operand keys spelled
     ``"bytes accessed0{}"`` / ``"bytes accessedout{}"``.  Returns::
 
         {"flops": float, "bytes": float, "transcendentals": float,
@@ -367,9 +366,7 @@ def normalize_cost_analysis(ca) -> dict:
     with no cost model) yields the all-zero record, never a KeyError.
     jax-free on purpose: the parsing is testable without a compile.
     """
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else None
-    if not isinstance(ca, dict):
+    if ca is None:
         ca = {}
     operand_bytes: dict[int, float] = {}
     utilization: dict[int, float] = {}

@@ -144,6 +144,25 @@ def test_ops_routes_flash_decode():
     assert out_x.shape == out.shape
 
 
+def test_ops_explicit_pallas_raises_without_a_kernel():
+    """impl='pallas' names the kernel: a call no kernel fits (cross-style
+    causal=False decode, a chunk of queries with explicit positions) raises
+    instead of running the reference unseen; impl='flash' still falls back."""
+    q, k, v, q_pos, kv_pos = _pool(jax.random.PRNGKey(9), 2, 32, 4, 2, 16,
+                                   lengths=[9, 25])
+    with pytest.raises(ValueError, match="no Pallas attention kernel"):
+        attention(q, k, v, q_pos=q_pos, kv_pos=kv_pos, causal=False,
+                  impl="pallas")
+    qc = jnp.concatenate([q, q], axis=1)               # (B, 2, Hq, hd)
+    pos2 = jnp.concatenate([q_pos, q_pos + 1], axis=1)
+    with pytest.raises(ValueError, match="no Pallas attention kernel"):
+        attention(qc, k, v, q_pos=pos2, kv_pos=kv_pos, causal=True,
+                  impl="pallas")
+    out = attention(qc, k, v, q_pos=pos2, kv_pos=kv_pos, causal=True,
+                    impl="flash")
+    assert out.shape == qc.shape
+
+
 @pytest.mark.parametrize("arch", ["qwen2.5-3b", "gemma2-9b"])
 def test_decode_step_flash_matches_ref(arch):
     """Full model decode_step: flash vs ref logits (gemma2 covers the

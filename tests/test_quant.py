@@ -122,6 +122,15 @@ def test_quant_matmul_untileable_falls_back():
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref))
 
 
+def test_quant_matmul_explicit_pallas_untileable_raises():
+    """impl='pallas' names the kernel: shapes it cannot tile raise instead
+    of silently running the reference."""
+    x = jax.random.normal(jax.random.PRNGKey(5), (3, 48))
+    qt = quantize(jax.random.normal(jax.random.PRNGKey(6), (48, 300)), 8)
+    with pytest.raises(ValueError, match="no dequant-matmul kernel"):
+        quant_matmul(x, qt, impl="pallas")
+
+
 # ---------------------------------------------------------------------------
 # quantised-KV decode kernel vs fp oracle
 # ---------------------------------------------------------------------------

@@ -381,3 +381,22 @@ def test_default_config_has_no_failure_paths(small_model):
     assert all(r.status == DONE for r in eng.finished)
     s = eng.stats()
     assert s["failed"] == 0 and s["rejected"] == 0
+
+
+def test_launcher_functions_build_and_drain():
+    """``python -m repro.launch.serve`` and ``chip_smoke.py`` share these
+    functions: parsed arguments -> engine -> seeded prompts -> drain."""
+    from repro.launch import serve
+
+    args = serve.parse_args(["--arch", "qwen2.5-3b", "--reduced",
+                             "--max-batch", "2", "--kv-len", "64",
+                             "--max-new-tokens", "3", "--impl", "flash"])
+    assert serve.parse_mesh("") is None
+    with pytest.raises(SystemExit):
+        serve.parse_mesh("2by2")
+    cfg, eng = serve.build_engine(args)
+    reqs = serve.submit_prompts(eng, 3, 4, 20, seed=0)
+    assert all(4 <= r.prompt.size < 20 for r in reqs)
+    eng.run_until_drained()
+    assert [r.status for r in reqs] == ["done"] * 3
+    assert all(len(r.output) == 3 for r in reqs)

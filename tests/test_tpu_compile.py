@@ -1,0 +1,167 @@
+"""Ahead-of-time compiles of the serving kernels for a described TPU v5e.
+
+Interpret mode runs the kernel bodies on the CPU but never meets the TPU's
+tiling and memory rules; these tests hand each kernel of the serving path
+to the TPU compiler at qwen2.5-3b's widths (Hq=16, Hkv=2, hd=128,
+d_model=2048, d_ff=11008) for a chip that is described, not attached.
+Nothing runs, so they check only that the compiler accepts the kernel and
+that a Mosaic custom call is in the program.
+
+The topology is described inside a fixture (never at import), and every
+case lives in this one file, so that only the test worker given this file
+loads the TPU library.
+"""
+import os
+import re
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from repro.kernels.flash_attention.decode import (flash_decode_fwd,
+                                                  flash_decode_quant_fwd)
+from repro.kernels.flash_attention.kernel import flash_attention_fwd
+from repro.quant.kernel import quant_matmul_pallas
+
+B, SKV, HQ, HKV, HD = 8, 1024, 16, 2, 128     # serving pool at qwen widths
+C = 128                                       # packed-prefill stream
+D_MODEL, D_FF = 2048, 11008
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:      # no TPU compiler in this installation
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    """A single described chip, with the persistent compilation cache off:
+    a compile for a described device is written but cannot be read back."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    from jax.sharding import SingleDeviceSharding
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+
+
+def _decode_fp(shape):
+    return (lambda q, k, v, qp, kp: flash_decode_fwd(q, k, v, q_pos=qp,
+                                                     kv_pos=kp),
+            [shape((B, 1, HQ, HD), jnp.bfloat16),
+             shape((B, SKV, HKV, HD), jnp.bfloat16),
+             shape((B, SKV, HKV, HD), jnp.bfloat16),
+             shape((B, 1), jnp.int32), shape((B, SKV), jnp.int32)])
+
+
+def _decode_quant(bits):
+    def case(shape):
+        hdq = HD // (2 if bits == 4 else 1)
+        return (lambda q, kq, ks, vq, vs, qp, kp: flash_decode_quant_fwd(
+                    q, kq, ks, vq, vs, kv_bits=bits, q_pos=qp, kv_pos=kp),
+                [shape((B, 1, HQ, HD), jnp.bfloat16),
+                 shape((B, SKV, HKV, hdq), jnp.int8),
+                 shape((B, SKV, HKV), jnp.float32),
+                 shape((B, SKV, HKV, hdq), jnp.int8),
+                 shape((B, SKV, HKV), jnp.float32),
+                 shape((B, 1), jnp.int32), shape((B, SKV), jnp.int32)])
+    return case
+
+
+def _packed_prefill(shape):
+    return (lambda q, k, v, seg: flash_attention_fwd(q, k, v, segments=seg,
+                                                     causal=True),
+            [shape((1, HQ, C, HD), jnp.bfloat16),
+             shape((1, HKV, C, HD), jnp.bfloat16),
+             shape((1, HKV, C, HD), jnp.bfloat16),
+             shape((1, C), jnp.int32)])
+
+
+def _dequant_matmul(bits):
+    def case(shape):
+        return (lambda x, q, s: quant_matmul_pallas(x, q, s, bits=bits),
+                [shape((B, D_MODEL), jnp.bfloat16),
+                 shape((D_MODEL // (2 if bits == 4 else 1), D_FF), jnp.int8),
+                 shape((1, D_FF), jnp.float32)])
+    return case
+
+
+CASES = {
+    "decode_fp": _decode_fp,
+    "decode_kv8": _decode_quant(8),
+    "decode_kv4": _decode_quant(4),
+    "packed_prefill": _packed_prefill,
+    "dequant_matmul_int8": _dequant_matmul(8),
+    "dequant_matmul_int4": _dequant_matmul(4),
+}
+
+
+@pytest.mark.parametrize("case", list(CASES))
+def test_kernel_compiles_for_v5e(one_chip, case):
+    def shape(s, dt):
+        return jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
+
+    fn, args = CASES[case](shape)
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_sharded_decode_step_compiles_for_v5e_2x2(topo, one_chip,
+                                                  monkeypatch):
+    """The engine's fused decode step with ``impl="flash"`` on a (data=2,
+    model=2) mesh of described chips, at qwen2.5-3b widths cut to two
+    layers.  GSPMD refuses to partition a Mosaic kernel, so the kernel
+    must reach the compiler inside a shard_map, with the KV pool local to
+    each device (no all-gather of it)."""
+    import dataclasses
+
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from repro.config import get_config
+    from repro.models import transformer as T
+    from repro.parallel.sharding import cache_shardings
+    from repro.serving.engine import EngineConfig
+    from repro.serving.executor import Executor
+
+    # ops picks compiled kernels for impl="flash" only on a TPU backend
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = dataclasses.replace(get_config("qwen2.5-3b"), n_layers=2)
+    mesh = Mesh(np.asarray(topo.devices).reshape(2, 2), ("data", "model"))
+    ex = Executor(cfg, None, EngineConfig(max_batch=B, kv_len=SKV,
+                                          impl="flash"), mesh=mesh)
+    rep = NamedSharding(mesh, P())
+
+    def placed(tree, shardings):
+        return jax.tree.map(lambda x, s: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=s), tree, shardings)
+
+    params = jax.eval_shape(lambda: T.init_params(
+        cfg, jax.random.PRNGKey(0), param_dtype=jnp.bfloat16))
+    cache = jax.eval_shape(lambda: T.init_cache(cfg, B, SKV))
+    state = jax.eval_shape(lambda: {
+        "tokens": jnp.zeros((B,), jnp.int32), "pos": jnp.zeros((B,), jnp.int32),
+        "budget": jnp.zeros((B,), jnp.int32), "live": jnp.zeros((B,), bool),
+        "key": jax.random.PRNGKey(0)})
+    text = ex.jit_step.lower(
+        placed(params, jax.tree.map(lambda _: rep, params)),
+        placed(cache, cache_shardings(cache, ex.shard_ctx)),
+        placed(state, jax.tree.map(lambda _: rep, state))).compile().as_text()
+    assert "tpu_custom_call" in text
+    # every all-gather result is far smaller than one layer's K pool
+    k_elems = B * SKV * HKV * HD
+    for line in text.splitlines():
+        if "all-gather" in line and "=" in line:
+            result = line.split("=", 1)[1].split("all-gather")[0]
+            for dims in re.findall(r"\[([\d,]*)\]", result):
+                n = np.prod([int(d) for d in dims.split(",") if d])
+                assert n < k_elems // 8, line
