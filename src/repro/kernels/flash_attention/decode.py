@@ -195,6 +195,8 @@ def flash_decode_fwd(
         out_specs=pl.BlockSpec((1, 1, 1, rep, hdv),
                                lambda b, h, ik: (b, h, 0, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((B, Hkv, 1, rep, hdv), q.dtype),
+        name="decode_attention",
+        metadata={"kernel": "decode_attention"},
         scratch_shapes=[
             vmem((rep, 1)),
             vmem((rep, 1)),
@@ -321,6 +323,8 @@ def flash_decode_quant_fwd(
         out_specs=pl.BlockSpec((1, 1, pack, rep, hdvq),
                                lambda b, h, ik: (b, h, 0, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((B, Hkv, pack, rep, hdvq), q.dtype),
+        name="decode_attention_quant",
+        metadata={"kernel": "decode_attention_quant"},
         scratch_shapes=[
             vmem((rep, 1)),
             vmem((rep, 1)),

@@ -171,6 +171,8 @@ def flash_attention_fwd(
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, 1, bq, hdv), lambda b, h, iq, ik: (b, h, iq, 0)),
         out_shape=jax.ShapeDtypeStruct((B, Hq, Sq, hdv), q.dtype),
+        name="flash_attention",
+        metadata={"kernel": "flash_attention"},
         scratch_shapes=[
             vmem((bq, 1)),
             vmem((bq, 1)),

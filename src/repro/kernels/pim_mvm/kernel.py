@@ -89,5 +89,7 @@ def pim_mvm_pallas(x, wq, scales, *, bm: int = 128, bn: int = 256,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((M, N), x.dtype),
         scratch_shapes=[_vmem((bm, bn))],
+        name="pim_mvm",
+        metadata={"kernel": "pim_mvm"},
         interpret=interpret,
     )(x, wq, scales)

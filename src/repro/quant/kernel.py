@@ -103,5 +103,7 @@ def quant_matmul_pallas(x, q, scale, *, bits: int, group: int = 0,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((M, N), x.dtype),
         scratch_shapes=[_vmem((bm, bn))],
+        name="quant_matmul",
+        metadata={"kernel": "quant_matmul"},
         interpret=interpret,
     )(*xs, q, scale)

@@ -65,6 +65,7 @@ from repro.config import ModelConfig
 from repro.serving.executor import Executor
 from repro.serving.pool import SlotPool
 from repro.serving.scheduler import FifoScheduler, Scheduler
+from repro.serving.tracing import Spans
 
 
 @dataclasses.dataclass
@@ -127,7 +128,8 @@ class EngineConfig:
     #   ``ServingEngine.trace`` and stats() surfaces aggregates under
     #   trace_* keys — present only when tracing, so the dormant
     #   engine's stats() stay bit-identical (the spec_k contract).
-    #   Durations come from time.perf_counter (real wall clock),
+    #   Durations come from time.perf_counter (real wall clock) at the
+    #   boundaries of the engine's host spans (serving/tracing.py),
     #   independent of ``clock=``, which fake-clock tests may drive.
 
 
@@ -271,6 +273,9 @@ class ServingEngine:
         # dict per decode iteration; the measured step times the
         # calibration plane (repro.profile) replays through Plane B
         self.trace: list[dict] = []
+        # host spans for the profiler, whose boundaries also time the
+        # records above
+        self.spans = Spans(wall=ecfg.trace)
 
         # packed-stream / chunk budget (also the padding quantum)
         S = ecfg.kv_len
@@ -402,7 +407,10 @@ class ServingEngine:
         return self.ecfg.clock()
 
     def _fetch(self, x) -> np.ndarray:
-        return self.executor.fetch(x)
+        with self.spans.span("executor.fetch") as sp:
+            arr = self.executor.fetch(x)
+            sp.set(lambda: {"bytes": arr.nbytes})
+        return arr
 
     # -- public API -------------------------------------------------------------
     def submit(self, prompt: np.ndarray, max_new_tokens: Optional[int] = None,
@@ -450,13 +458,54 @@ class ServingEngine:
         """One engine iteration: deadline eviction + (scheduler-gated)
         admission + chunked prefill continuation + one decode step over
         the slot pool.  Returns the number of occupied slots."""
-        if self.ecfg.deadline_ms > 0:
-            self._evict_expired()
-        if self.ecfg.spec_k:
-            return self._step_spec()
-        if self.ecfg.fused:
-            return self._step_fused()
-        return self._step_host()
+        self.spans.begin()
+        with self.spans.span("engine.step", self._step_attrs):
+            if self.ecfg.deadline_ms > 0:
+                self._evict_expired()
+            if self.ecfg.spec_k:
+                return self._step_spec()
+            if self.ecfg.fused:
+                return self._step_fused()
+            return self._step_host()
+
+    # -- span attributes (built only while the profiler records) ---------------
+    def _step_attrs(self) -> dict:
+        return {"it": self.spans.it, "queue": len(self.queue),
+                "occupied": self.pool.occupied(),
+                "decoding": len(self.pool.decoding())}
+
+    def _decode_attrs(self) -> dict:
+        """The decoding requests, and the live KV entries over every
+        occupied slot once this step has written (a prefilling slot counts
+        the prompt positions written so far)."""
+        pool, uids, kv = self.pool, [], 0
+        for i, req in enumerate(pool.slot_req):
+            if req is None:
+                continue
+            if i in pool.prefilling:
+                kv += pool.prefilling[i][0]
+                continue
+            uids.append(req.uid)
+            kv += len(req.prompt) + len(req.output)
+        return {"uids": uids, "decoding": len(uids), "kv_live": kv}
+
+    def _commit_attrs(self, tokens: int, f0: int) -> Callable[[], dict]:
+        """Attributes of a commit loop: the tokens it committed and the
+        requests it finished (``self.finished`` from index ``f0``)."""
+        return lambda: {"tokens": tokens,
+                        "finished": [r.uid for r in self.finished[f0:]]}
+
+    def _trace_iteration(self, t0: float, dt: float, dispatch: str,
+                         iters: int) -> None:
+        """One ``EngineConfig(trace=)`` record, from the wall clock at the
+        boundaries of this iteration's spans: the decode ``dispatch`` and
+        the ``executor.fetch`` that followed it (dispatch is asynchronous,
+        so the fetch waits on the device step and decode_s + d2h_s is the
+        step's wall time)."""
+        (a, b), c = self.spans.marks[dispatch], \
+            self.spans.marks["executor.fetch"][1]
+        self.trace.append({"prefill_s": dt, "decode_s": b - a,
+                           "d2h_s": c - b, "step_s": c - t0, "iters": iters})
 
     # -- failure plumbing ------------------------------------------------------
     def _fail(self, req: Request, status: str, now: Optional[float] = None):
@@ -469,19 +518,22 @@ class ServingEngine:
         """Fail every queued or in-flight request past its deadline —
         expired work is dropped before it spends another admission or
         decode step (the slot frees for a request that can still make it)."""
-        now = self._now()
-        if self.queue:
-            kept = collections.deque()
-            for req in self.queue:
-                if now > req.deadline:
+        with self.spans.span("engine.evict") as sp:
+            f0 = len(self.failed)
+            now = self._now()
+            if self.queue:
+                kept = collections.deque()
+                for req in self.queue:
+                    if now > req.deadline:
+                        self._fail(req, FAILED_DEADLINE, now)
+                    else:
+                        kept.append(req)
+                self.queue = kept
+            for i, req in enumerate(self.pool.slot_req):
+                if req is not None and now > req.deadline:
                     self._fail(req, FAILED_DEADLINE, now)
-                else:
-                    kept.append(req)
-            self.queue = kept
-        for i, req in enumerate(self.pool.slot_req):
-            if req is not None and now > req.deadline:
-                self._fail(req, FAILED_DEADLINE, now)
-                self.pool.kill(i)
+                    self.pool.kill(i)
+            sp.set(lambda: {"uids": [r.uid for r in self.failed[f0:]]})
 
     # -- scheduler seams -------------------------------------------------------
     def _prefill_allowed(self) -> bool:
@@ -523,40 +575,49 @@ class ServingEngine:
         return None
 
     # -- iteration loop --------------------------------------------------------
-    def _step_fused(self) -> int:
+    def _admission(self, admit: Callable[[], None]) -> tuple[float, float]:
+        """The scheduler-gated admission phase of an iteration: its wall
+        clock start and duration, which feed ``prefill_time`` and the
+        scheduler's prefill cost estimate."""
         t0 = time.perf_counter()
         calls0 = self.prefill_calls
         if self._prefill_allowed():
-            if self.ecfg.packed:
-                self._admit_packed()
-            else:
-                self._admit_fused()
+            admit()
         dt = time.perf_counter() - t0
         self.prefill_time += dt
         if self.prefill_calls > calls0:
             self.scheduler.observe_prefill(dt)
+        return t0, dt
+
+    def _step_fused(self) -> int:
+        t0, dt = self._admission(self._admit_packed if self.ecfg.packed
+                                 else self._admit_fused)
         occupied = self.pool.occupied()
         if occupied == len(self.pool.prefilling):
             # no live slot: nothing to decode (and nothing being stalled —
             # mid-prefill-only iterations just advance their chunks)
             self._stall_tokens = 0
             return occupied
-        tr = self.ecfg.trace
-        td0 = time.perf_counter() if tr else 0.0
-        self.pool.cache, self.pool.state, packed = self.executor.fused_step(
-            self.pool.cache, self.pool.state)
-        td1 = time.perf_counter() if tr else 0.0
+        with self.spans.span("executor.fused_step", self._decode_attrs):
+            self.pool.cache, self.pool.state, packed = \
+                self.executor.fused_step(self.pool.cache, self.pool.state)
         arr = self._fetch(packed)                 # ONE d2h transfer
-        if tr:
-            # dispatch is asynchronous: the d2h fetch waits on the device
-            # step, so decode_s + d2h_s is the true step wall time
-            td2 = time.perf_counter()
-            self.trace.append({"prefill_s": dt, "decode_s": td1 - td0,
-                               "d2h_s": td2 - td1, "step_s": td2 - t0,
-                               "iters": int(arr.shape[0])})
+        if self.ecfg.trace:
+            self._trace_iteration(t0, dt, "executor.fused_step",
+                                  int(arr.shape[0]))
         self.decode_steps += arr.shape[0]
         self.max_stall_tokens = max(self.max_stall_tokens, self._stall_tokens)
         self._stall_tokens = 0
+        with self.spans.span("engine.commit") as sp:
+            f0 = len(self.finished)
+            tokens = self._commit_fused(arr)
+            sp.set(self._commit_attrs(tokens, f0))
+        return self.pool.occupied()
+
+    def _commit_fused(self, arr: np.ndarray) -> int:
+        """Commit a fused step's fetched ``(K, 3, B)`` tokens; returns how
+        many were committed."""
+        tokens = 0
         now = self._now()
         for it in range(arr.shape[0]):            # decode_chunk iterations
             # zero-active iterations (slots all finished mid-chunk) are real
@@ -584,13 +645,14 @@ class ServingEngine:
                 if not req.output:
                     req.t_first_token = now
                 req.output.append(tok)
+                tokens += 1
                 if arr[it, 1, i]:
                     req.done = True
                     req.status = DONE
                     req.t_done = now
                     self.finished.append(req)
                     self.pool.release(i)     # slot freed → continuous batching
-        return self.pool.occupied()
+        return tokens
 
     def _step_spec(self) -> int:
         """One speculative iteration: admission (same packed path), then a
@@ -598,36 +660,32 @@ class ServingEngine:
         transfer — a packed ``(spec_k+1, 4, B)`` of (token | -1, done,
         anomaly, n_accepted) — commits up to ``spec_k + 1`` tokens per
         slot per weight stream."""
-        t0 = time.perf_counter()
-        calls0 = self.prefill_calls
-        if self._prefill_allowed():
-            self._admit_packed()
-        dt = time.perf_counter() - t0
-        self.prefill_time += dt
-        if self.prefill_calls > calls0:
-            self.scheduler.observe_prefill(dt)
+        t0, dt = self._admission(self._admit_packed)
         occupied = self.pool.occupied()
         if occupied == len(self.pool.prefilling):
             self._stall_tokens = 0
             return occupied
-        tr = self.ecfg.trace
-        td0 = time.perf_counter() if tr else 0.0
-        self.pool.cache, dcache, self.pool.state, packed = \
-            self.executor.spec_step(self.pool.cache, self.pool.state,
-                                    self.pool.draft_cache)
-        td1 = time.perf_counter() if tr else 0.0
+        with self.spans.span("executor.spec_step", self._decode_attrs):
+            self.pool.cache, dcache, self.pool.state, packed = \
+                self.executor.spec_step(self.pool.cache, self.pool.state,
+                                        self.pool.draft_cache)
         if self.pool.draft_cache is not None:
             self.pool.draft_cache = dcache
         arr = self._fetch(packed)                 # ONE d2h transfer
-        if tr:
-            td2 = time.perf_counter()
-            self.trace.append({"prefill_s": dt, "decode_s": td1 - td0,
-                               "d2h_s": td2 - td1, "step_s": td2 - t0,
-                               "iters": 1})
+        if self.ecfg.trace:
+            self._trace_iteration(t0, dt, "executor.spec_step", 1)
         self.decode_steps += 1                    # one target weight stream
         self.spec_steps += 1
         self.max_stall_tokens = max(self.max_stall_tokens, self._stall_tokens)
         self._stall_tokens = 0
+        with self.spans.span("engine.commit") as sp:
+            f0, c0 = len(self.finished), self.spec_committed
+            self._commit_spec(arr)
+            sp.set(self._commit_attrs(self.spec_committed - c0, f0))
+        return self.pool.occupied()
+
+    def _commit_spec(self, arr: np.ndarray) -> None:
+        """Commit a speculative step's fetched ``(spec_k+1, 4, B)``."""
         now = self._now()
         K = self.ecfg.spec_k
         # occupancy accounting mirrors the fused step: slots that committed
@@ -665,18 +723,10 @@ class ServingEngine:
                     self.finished.append(req)
                     self.pool.release(i)
                     break
-        return self.pool.occupied()
 
     def _step_host(self) -> int:
         """Original per-token host round-trip step (measurement baseline)."""
-        t0 = time.perf_counter()
-        calls0 = self.prefill_calls
-        if self._prefill_allowed():
-            self._admit_host()
-        dt = time.perf_counter() - t0
-        self.prefill_time += dt
-        if self.prefill_calls > calls0:
-            self.scheduler.observe_prefill(dt)
+        t0, dt = self._admission(self._admit_host)
         live = [i for i, r in enumerate(self.pool.slot_req) if r is not None]
         if not live:
             return 0
@@ -684,22 +734,29 @@ class ServingEngine:
         self.active_slot_hist[len(live)] += 1
         tokens = jnp.asarray(host["last_token"])
         pos = jnp.asarray(host["slot_pos"])
-        tr = self.ecfg.trace
-        td0 = time.perf_counter() if tr else 0.0
-        logits, self.pool.cache = self.executor.decode(self.pool.cache,
-                                                       tokens, pos)
-        td1 = time.perf_counter() if tr else 0.0
+        with self.spans.span("executor.decode", self._decode_attrs):
+            logits, self.pool.cache = self.executor.decode(self.pool.cache,
+                                                           tokens, pos)
         self.decode_steps += 1
         self.max_stall_tokens = max(self.max_stall_tokens, self._stall_tokens)
         self._stall_tokens = 0
-        nxt, self._key = self.executor.sample_host(logits, self._key)
-        if tr:
-            # the host-path "d2h" is the sampling round-trip that waits
-            # on the decode dispatch — same split as the fused path
-            td2 = time.perf_counter()
-            self.trace.append({"prefill_s": dt, "decode_s": td1 - td0,
-                               "d2h_s": td2 - td1, "step_s": td2 - t0,
-                               "iters": 1})
+        # the host-path d2h is the sampling round trip that waits on the
+        # decode dispatch — the same split as the fused path
+        nxt = self._sample_host(logits)
+        if self.ecfg.trace:
+            self._trace_iteration(t0, dt, "executor.decode", 1)
+        with self.spans.span("engine.commit") as sp:
+            f0 = len(self.finished)
+            self._commit_host(live, nxt, host)
+            sp.set(self._commit_attrs(len(live), f0))
+        return self.pool.occupied()
+
+    def _sample_host(self, logits) -> np.ndarray:
+        with self.spans.span("executor.fetch"):
+            nxt, self._key = self.executor.sample_host(logits, self._key)
+        return nxt
+
+    def _commit_host(self, live: list, nxt: np.ndarray, host: dict) -> None:
         now = self._now()
         for i in live:
             req = self.pool.slot_req[i]
@@ -718,7 +775,6 @@ class ServingEngine:
                 req.t_done = now
                 self.finished.append(req)
                 self.pool.release(i)     # slot freed → continuous batching
-        return self.pool.occupied()
 
     def run_until_drained(self, max_iters: int = 10_000) -> list[Request]:
         """Step until every request reaches a terminal state.
@@ -756,7 +812,6 @@ class ServingEngine:
         return min(-(-max(plen, 1) // C) * C, self.ecfg.kv_len)
 
     def _admit_packed(self):
-        B, C = self.ecfg.max_batch, self._chunk
         if self.pool.prefilling:
             self._continue_chunks()
         free = self.pool.free_slots()
@@ -765,7 +820,13 @@ class ServingEngine:
         if not self._packable:
             self._admit_padded(free)
             return
+        with self.spans.span("engine.admit") as sp:
+            self._admit_stream(free, sp)
 
+    def _admit_stream(self, free: list, sp) -> None:
+        """Pack the scheduler's picks into one ``(1, C)`` stream, prefill
+        it in one call and commit each complete prompt's first token."""
+        B, C = self.ecfg.max_batch, self._chunk
         segs = []                      # (req, slot, off, take, final, budget)
         used = 0
         try:
@@ -794,6 +855,11 @@ class ServingEngine:
         if not segs:
             return
 
+        def uids():
+            return [s[0].uid for s in segs]
+
+        sp.set(lambda: {"uids": uids(), "prompt_tokens": used, "stream": C})
+
         toks = np.zeros((1, C), np.int32)
         seg = np.full((1, C), -1, np.int32)
         pos = np.zeros((1, C), np.int32)
@@ -814,21 +880,32 @@ class ServingEngine:
             off_v[slot], len_v[slot] = off, take
             fin_v[slot], bud_v[slot], act_v[slot] = final, budget, True
 
-        self.pool.cache, self.pool.state, first = self.executor.packed_prefill(
-            self.pool.cache, self.pool.state, jnp.asarray(toks),
-            jnp.asarray(pos), jnp.asarray(seg), jnp.asarray(gather),
-            jnp.asarray(off_v), jnp.asarray(len_v), jnp.asarray(fin_v),
-            jnp.asarray(bud_v), jnp.asarray(act_v))
+        args = [jnp.asarray(a) for a in (toks, pos, seg, gather, off_v,
+                                         len_v, fin_v, bud_v, act_v)]
+        with self.spans.span("executor.packed_prefill",
+                             lambda: {"uids": uids()}):
+            self.pool.cache, self.pool.state, first = \
+                self.executor.packed_prefill(self.pool.cache,
+                                             self.pool.state, *args)
         arr = self._fetch(first)                  # one d2h per admission burst
         self.prefill_tokens += used
         self.prefill_calls += 1
         self._stall_tokens += used
+        with self.spans.span("engine.commit") as cm:
+            f0 = len(self.finished)
+            tokens = self._commit_stream(segs, arr)
+            cm.set(self._commit_attrs(tokens, f0))
+
+    def _commit_stream(self, segs: list, arr: np.ndarray) -> int:
+        """Commit a packed prefill's first tokens; returns how many."""
+        tokens = 0
         now = self._now()
         for req, slot, off, take, final, budget in segs:
             if final:
                 tok = int(arr[slot])
                 req.output = [tok]
                 req.t_first_token = now
+                tokens += 1
                 if budget == 1:     # the prefill sample was the whole budget
                     req.done = True
                     req.status = DONE
@@ -842,10 +919,15 @@ class ServingEngine:
                 req.status = ACTIVE
                 self.pool.slot_req[slot] = req
                 self.pool.prefilling[slot] = (take, budget)
+        return tokens
 
     def _continue_chunks(self):
         """Advance every mid-prefill slot by one <= C-token chunk (one
         batched jitted call), activating rows whose prompt completed."""
+        with self.spans.span("engine.chunks") as sp:
+            self._chunk_rows(sp)
+
+    def _chunk_rows(self, sp) -> None:
         B, C = self.ecfg.max_batch, self._chunk
         toks = np.zeros((B, C), np.int32)
         pos = np.full((B, C), -1, np.int32)
@@ -863,16 +945,34 @@ class ServingEngine:
             fin_v[slot] = start + c == plen
             bud_v[slot] = budget
             plan.append((slot, start, c, budget))
-
-        self.pool.cache, self.pool.state, first = self.executor.chunk_step(
-            self.pool.cache, self.pool.state, jnp.asarray(toks),
-            jnp.asarray(pos), jnp.asarray(take_idx), jnp.asarray(fin_v),
-            jnp.asarray(bud_v))
-        arr = self._fetch(first)
         total = sum(c for _, _, c, _ in plan)
+
+        def uids():
+            return [self.pool.slot_req[slot].uid for slot, *_ in plan]
+
+        # the chunk step computes every row of the (B, C) block: prompt
+        # tokens over positions is the share of it that is useful work
+        sp.set(lambda: {"uids": uids(), "rows": len(plan),
+                        "prompt_tokens": total, "positions": B * C})
+
+        args = [jnp.asarray(a) for a in (toks, pos, take_idx, fin_v, bud_v)]
+        with self.spans.span("executor.chunk_step", lambda: {"uids": uids()}):
+            self.pool.cache, self.pool.state, first = \
+                self.executor.chunk_step(self.pool.cache, self.pool.state,
+                                         *args)
+        arr = self._fetch(first)
         self.prefill_tokens += total
         self.prefill_calls += 1
         self._stall_tokens += C                    # one batched chunk call
+        with self.spans.span("engine.commit") as cm:
+            f0 = len(self.finished)
+            tokens = self._commit_chunks(plan, arr)
+            cm.set(self._commit_attrs(tokens, f0))
+
+    def _commit_chunks(self, plan: list, arr: np.ndarray) -> int:
+        """Commit the first token of each prompt a chunk step completed;
+        returns how many."""
+        tokens = 0
         now = self._now()
         for slot, start, c, budget in plan:
             req = self.pool.slot_req[slot]
@@ -881,6 +981,7 @@ class ServingEngine:
                 tok = int(arr[slot])
                 req.output = [tok]
                 req.t_first_token = now
+                tokens += 1
                 if budget == 1:
                     req.done = True
                     req.status = DONE
@@ -891,6 +992,7 @@ class ServingEngine:
                     self._draft_ingest(req, slot)
             else:
                 self.pool.prefilling[slot] = (start + c, budget)
+        return tokens
 
     def _draft_ingest(self, req, slot: int) -> None:
         """Draft-model speculation: mirror a completed prompt into the
@@ -910,26 +1012,36 @@ class ServingEngine:
     def _admit_one(self, req, slot: int, plen: int, budget: int, pad: int):
         """One right-padded batch-1 prefill+insert call and its bookkeeping
         (shared by the chunk-padded and pow2-bucketed sequential paths)."""
-        req.t_admit = self._now()
-        toks = np.zeros((1, pad), np.int32)
-        toks[0, :plen] = req.prompt
-        self.pool.cache, self.pool.state, first = self.executor.prefill_insert(
-            self.pool.cache, self.pool.state, jnp.asarray(toks),
-            jnp.int32(slot), jnp.int32(plen), jnp.int32(budget))
-        tok = int(self._fetch(first))
-        self.prefill_tokens += plen
-        self.prefill_calls += 1
-        self._stall_tokens += pad
-        req.output = [tok]
-        req.t_first_token = self._now()
-        if budget == 1:             # the prefill sample was the whole budget
-            req.done = True
-            req.status = DONE
-            req.t_done = req.t_first_token
-            self.finished.append(req)
-        else:
-            req.status = ACTIVE
-            self.pool.slot_req[slot] = req
+        spans = self.spans
+        with spans.span("engine.admit",
+                        lambda: {"uids": [req.uid], "prompt_tokens": plen}):
+            req.t_admit = self._now()
+            toks = np.zeros((1, pad), np.int32)
+            toks[0, :plen] = req.prompt
+            args = (jnp.asarray(toks), jnp.int32(slot), jnp.int32(plen),
+                    jnp.int32(budget))
+            with spans.span("executor.prefill_insert",
+                            lambda: {"uids": [req.uid]}):
+                self.pool.cache, self.pool.state, first = \
+                    self.executor.prefill_insert(self.pool.cache,
+                                                 self.pool.state, *args)
+            tok = int(self._fetch(first))
+            self.prefill_tokens += plen
+            self.prefill_calls += 1
+            self._stall_tokens += pad
+            with spans.span("engine.commit") as cm:
+                f0 = len(self.finished)
+                req.output = [tok]
+                req.t_first_token = self._now()
+                if budget == 1:     # the prefill sample was the whole budget
+                    req.done = True
+                    req.status = DONE
+                    req.t_done = req.t_first_token
+                    self.finished.append(req)
+                else:
+                    req.status = ACTIVE
+                    self.pool.slot_req[slot] = req
+                cm.set(self._commit_attrs(1, f0))
 
     def _admit_padded(self, free):
         """Per-request admission for non-packable architectures: prompts
@@ -973,15 +1085,24 @@ class ServingEngine:
             if nxt is None:
                 continue
             req, toks, plen, budget = nxt
-            req.t_admit = self._now()
+            with self.spans.span("engine.admit", lambda: {
+                    "uids": [req.uid], "prompt_tokens": plen}):
+                self._admit_host_one(req, slot, toks, plen, budget, host)
+
+    def _admit_host_one(self, req, slot: int, toks: np.ndarray, plen: int,
+                        budget: int, host: dict) -> None:
+        req.t_admit = self._now()
+        with self.spans.span("executor.prefill", lambda: {"uids": [req.uid]}):
             logits, pcache = self.executor.prefill(jnp.asarray(toks),
                                                    jnp.int32(plen))
             self.pool.cache = self.executor.insert(
                 self.pool.cache, pcache, jnp.int32(slot), jnp.int32(plen))
-            first, self._key = self.executor.sample_host(logits, self._key)
-            self.prefill_tokens += plen
-            self.prefill_calls += 1
-            self._stall_tokens += toks.shape[1]
+        first = self._sample_host(logits)
+        self.prefill_tokens += plen
+        self.prefill_calls += 1
+        self._stall_tokens += toks.shape[1]
+        with self.spans.span("engine.commit") as cm:
+            f0 = len(self.finished)
             req.output = [int(first[0])]
             req.t_first_token = self._now()
             if budget == 1:         # the prefill sample was the whole budget
@@ -989,12 +1110,13 @@ class ServingEngine:
                 req.status = DONE
                 req.t_done = req.t_first_token
                 self.finished.append(req)
-                continue
-            req.status = ACTIVE
-            self.pool.slot_req[slot] = req
-            host["slot_pos"][slot] = plen
-            host["slot_budget"][slot] = budget - 1
-            host["last_token"][slot] = int(first[0])
+            else:
+                req.status = ACTIVE
+                self.pool.slot_req[slot] = req
+                host["slot_pos"][slot] = plen
+                host["slot_budget"][slot] = budget - 1
+                host["last_token"][slot] = int(first[0])
+            cm.set(self._commit_attrs(1, f0))
 
     # -- crash safety ---------------------------------------------------------
     @classmethod

@@ -5,7 +5,8 @@ tiling and memory rules; these tests hand each kernel of the serving path
 to the TPU compiler at qwen2.5-3b's widths (Hq=16, Hkv=2, hd=128,
 d_model=2048, d_ff=11008) for a chip that is described, not attached.
 Nothing runs, so they check only that the compiler accepts the kernel and
-that a Mosaic custom call is in the program.
+that a Mosaic custom call is in the program, named by its
+``kernel_metadata`` (the text a chip trace carries for the op).
 
 The topology is described inside a fixture (never at import), and every
 case lives in this one file, so that only the test worker given this file
@@ -103,6 +104,18 @@ CASES = {
     "dequant_matmul_int8": _dequant_matmul(8),
     "dequant_matmul_int4": _dequant_matmul(4),
 }
+KERNEL = {"decode_fp": "decode_attention",
+          "decode_kv8": "decode_attention_quant",
+          "decode_kv4": "decode_attention_quant",
+          "packed_prefill": "flash_attention",
+          "dequant_matmul_int8": "quant_matmul",
+          "dequant_matmul_int4": "quant_matmul"}
+
+
+def _kernels(text: str) -> set:
+    """Names in the ``kernel_metadata`` of the program's custom calls."""
+    return set(re.findall(r'kernel_metadata=\{\s*"kernel":"(\w+)"\s*\}',
+                          text))
 
 
 @pytest.mark.parametrize("case", list(CASES))
@@ -111,8 +124,9 @@ def test_kernel_compiles_for_v5e(one_chip, case):
         return jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
 
     fn, args = CASES[case](shape)
-    compiled = jax.jit(fn).lower(*args).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text
+    assert _kernels(text) == {KERNEL[case]}
 
 
 def test_sharded_decode_step_compiles_for_v5e_2x2(topo, one_chip,
@@ -157,6 +171,7 @@ def test_sharded_decode_step_compiles_for_v5e_2x2(topo, one_chip,
         placed(cache, cache_shardings(cache, ex.shard_ctx)),
         placed(state, jax.tree.map(lambda _: rep, state))).compile().as_text()
     assert "tpu_custom_call" in text
+    assert _kernels(text) == {"decode_attention"}
     # every all-gather result is far smaller than one layer's K pool
     k_elems = B * SKV * HKV * HD
     for line in text.splitlines():
