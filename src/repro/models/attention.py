@@ -179,7 +179,7 @@ def _pad_pos(pos, cap):
     return jnp.concatenate([pos, jnp.full((B, cap - S), -1, jnp.int32)], axis=1)
 
 
-def _ring_write(cache, new_leaves: dict, pos):
+def _ring_write(cache, new_leaves: dict, pos, layer=None):
     """Write S tokens at per-(row, token) ``pos`` into the cache (ring for
     local, direct for global).  ``new_leaves`` maps cache leaf names to the
     (B, S, ...) values to commit — ``{"k", "v"}`` for fp pools, the
@@ -187,32 +187,48 @@ def _ring_write(cache, new_leaves: dict, pos):
     layouts.  ``pos < 0`` entries are dropped — dead pool slots and chunk
     pads never touch the cache.  Within one call only the last ``cap``
     positions of a row survive the ring, so those are the only ones written
-    (keeps scatter indices unique per row)."""
-    cap = cache["pos"].shape[1]
+    (keeps scatter indices unique per row).
+
+    ``layer`` (layer index): the cache leaves are the stacked pool
+    ``(L, B, cap, ...)`` and the rows land at ``[layer, row, slot]`` — one
+    scatter into the stacked leaf, which a layer scan carrying the donated
+    pool updates in place."""
+    cap = cache["pos"].shape[-1]
     B, S = pos.shape
     row_max = jnp.max(jnp.where(pos >= 0, pos, -1), axis=1, keepdims=True)
     valid = (pos >= 0) & (pos > row_max - cap)
     slot = jnp.where(valid, pos % cap, cap)          # cap = out of bounds
     bidx = jnp.arange(B)[:, None]
-    new = {name: cache[name].at[bidx, slot].set(
+    idx = (bidx, slot) if layer is None else (layer, bidx, slot)
+    new = {name: cache[name].at[idx].set(
         leaf.astype(cache[name].dtype), mode="drop")
         for name, leaf in new_leaves.items()}
-    new["pos"] = cache["pos"].at[bidx, slot].set(pos, mode="drop")
+    new["pos"] = cache["pos"].at[idx].set(pos, mode="drop")
     return new
 
 
-def _commit_kv(cache, new_k, new_v, pos):
+def _commit_kv(cache, new_k, new_v, pos, layer=None):
     """Commit fresh K/V rows into the slot pool: fp pools write the rows
     as-is; quantised pools quantise on commit (one symmetric scale per
     (token, head) row, int8 codes, packed for int4) so an fp copy of the
-    cache never exists between steps."""
+    cache never exists between steps.  ``layer``: see :func:`_ring_write`."""
     if "k_q" in cache:
         bits = kv_cache_bits(cache, new_k.shape[-1])
         k_q, k_s = quantize_kv(new_k, bits)
         v_q, v_s = quantize_kv(new_v, bits)
         return _ring_write(cache, {"k_q": k_q, "k_s": k_s,
-                                   "v_q": v_q, "v_s": v_s}, pos)
-    return _ring_write(cache, {"k": new_k, "v": new_v}, pos)
+                                   "v_q": v_q, "v_s": v_s}, pos, layer)
+    return _ring_write(cache, {"k": new_k, "v": new_v}, pos, layer)
+
+
+def layer_of(cache, layer):
+    """Layer ``layer`` of a layer-stacked cache tree (the tree itself when
+    ``layer`` is None)."""
+    if layer is None:
+        return cache
+    return jax.tree_util.tree_map(
+        lambda a: jax.lax.dynamic_index_in_dim(a, layer, 0, keepdims=False),
+        cache)
 
 
 # ---------------------------------------------------------------------------
@@ -235,6 +251,8 @@ def apply_attention(
     length=None,             # prefill: true prompt length of a padded stream
     segments=None,           # prefill: (B, S) packed prompt ids, -1 = pad
     kv_bits: int = 0,        # prefill: 8/4 returns a quantised cache
+    layer=None,              # decode/chunk: ``cache`` is the layer-stacked
+                             # pool; rows commit in place at this layer
 ):
     B, S, D = x.shape
     Hq, Hkv, hd, hdv = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim, cfg.v_head_dim
@@ -327,31 +345,32 @@ def apply_attention(
     else:  # decode (S == 1 — Pallas decode kernel) / chunk (S-token write)
         quant = "k_q" in cache
         bits = kv_cache_bits(cache, hd) if quant else 0
-        new_cache = _commit_kv(cache, k, v, pos)
+        new_cache = _commit_kv(cache, k, v, pos, layer)
+        # chunk attends to the PRE-write cache plus the in-stream chunk: the
+        # chunk write may evict ring entries that early chunk queries still
+        # need (their window reaches back before the chunk), and cache
+        # positions are all < the chunk's, so no duplicates.  Decode reads
+        # the cache with its new row committed.
+        cur = layer_of(cache if mode == "chunk" else new_cache, layer)
         qkw = {}
         if mode == "chunk":
-            # attend to the PRE-write cache plus the in-stream chunk: the
-            # chunk write may evict ring entries that early chunk queries
-            # still need (their window reaches back before the chunk), and
-            # cache positions are all < the chunk's, so no duplicates.
-            # A quantised cache is dequantised for the read (the committed
+            # a quantised cache is dequantised for the read (the committed
             # pool stays int8; the in-stream chunk attends at fp)
             if quant:
-                ck = dequantize_kv(cache["k_q"], cache["k_s"], bits)
-                cv = dequantize_kv(cache["v_q"], cache["v_s"], bits)
+                ck = dequantize_kv(cur["k_q"], cur["k_s"], bits)
+                cv = dequantize_kv(cur["v_q"], cur["v_s"], bits)
             else:
-                ck, cv = cache["k"], cache["v"]
+                ck, cv = cur["k"], cur["v"]
             kc = jnp.concatenate([ck.astype(k.dtype), k], axis=1)
             vc = jnp.concatenate([cv.astype(v.dtype), v], axis=1)
-            kv_pos = jnp.concatenate([cache["pos"], pos], axis=1)
+            kv_pos = jnp.concatenate([cur["pos"], pos], axis=1)
         elif quant:
             # dequantise-on-read decode: codes + scales go straight to the
             # kernel route (in-VMEM dequant); the fp cache never exists
-            kc, vc, kv_pos = new_cache["k_q"], new_cache["v_q"], new_cache["pos"]
-            qkw = dict(k_scale=new_cache["k_s"], v_scale=new_cache["v_s"],
-                       kv_bits=bits)
+            kc, vc, kv_pos = cur["k_q"], cur["v_q"], cur["pos"]
+            qkw = dict(k_scale=cur["k_s"], v_scale=cur["v_s"], kv_bits=bits)
         else:
-            kc, vc, kv_pos = new_cache["k"], new_cache["v"], new_cache["pos"]
+            kc, vc, kv_pos = cur["k"], cur["v"], cur["pos"]
         out = flash_attention(
             q, kc, vc,
             q_pos=pos, kv_pos=kv_pos, kv_valid=kv_pos >= 0,

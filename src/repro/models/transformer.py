@@ -18,7 +18,8 @@ import jax.numpy as jnp
 from repro.config import ModelConfig
 from repro.models import modules as M
 from repro.models.attention import (
-    apply_attention, apply_mla, init_attention, init_mla, init_kv_cache)
+    apply_attention, apply_mla, init_attention, init_mla, init_kv_cache,
+    layer_of)
 from repro.models.moe import apply_moe, init_moe, router_aux_loss
 from repro.quant.ops import qdense
 from repro.models.rglru import apply_rglru, init_rglru, init_rglru_cache
@@ -156,7 +157,9 @@ def init_params(cfg: ModelConfig, key, *, param_dtype=jnp.float32):
 
 def _apply_layer(p, x, *, cfg, kind, use_moe, mode, pos, cache, cross_src,
                  impl, causal, kv_cap=0, length=None, segments=None,
-                 kv_bits=0):
+                 kv_bits=0, layer=None):
+    """``layer``: ``cache["attn"]`` is the layer-stacked self-attention pool
+    and this layer's rows commit into it in place (decode / chunk)."""
     aux = jnp.zeros((), jnp.float32)
     new_cache = None
     if kind == "ssm":
@@ -190,7 +193,7 @@ def _apply_layer(p, x, *, cfg, kind, use_moe, mode, pos, cache, cross_src,
         out, c = apply_attention(p["attn"], h, cfg=cfg, kind=kind, mode=mode,
                                  pos=pos, cache=c_self, impl=impl, causal=causal,
                                  kv_cap=kv_cap, length=length, segments=segments,
-                                 kv_bits=kv_bits)
+                                 kv_bits=kv_bits, layer=layer)
         x = constrain(x + out + M.apply_mlp(p["mlp"], h, cfg), "residual")
         return x, ({"attn": c} if mode != "train" else None), aux
 
@@ -204,7 +207,7 @@ def _apply_layer(p, x, *, cfg, kind, use_moe, mode, pos, cache, cross_src,
         out, c = apply_attention(p["attn"], h, cfg=cfg, kind=kind, mode=mode,
                                  pos=pos, cache=c_self, impl=impl, causal=causal,
                                  kv_cap=kv_cap, length=length, segments=segments,
-                                 kv_bits=kv_bits)
+                                 kv_bits=kv_bits, layer=layer)
     if cfg.post_norm:
         out = M.apply_norm(p["ln1_post"], out)
     x = constrain(x + out, "residual")
@@ -242,17 +245,68 @@ def _apply_layer(p, x, *, cfg, kind, use_moe, mode, pos, cache, cross_src,
 # stack runner (scan groups)
 # ---------------------------------------------------------------------------
 
+# Decode / chunk groups with at most this many repeats run the layer body
+# as an unrolled Python loop; larger groups scan it.  The small
+# configurations the CPU tests run stay unrolled: XLA's CPU backend rounds
+# bf16 differently inside a loop body, and their greedy token streams,
+# compared across serving paths and with recorded ones, hold near ties
+# that this rounding decides.
+DECODE_UNROLL = 8
+
+
+def _commits_in_place(cfg, kind: str) -> bool:
+    """Whether a unit of this kind commits its decode / chunk K/V rows in
+    place into the layer-stacked pool (standard and parallel-block self
+    attention, fp or quantised); every other unit kind returns its whole
+    layer state, which the layer loop writes back."""
+    return kind in ("global", "local") and not cfg.is_mla
+
+
+def kv_commit_layers(cfg, groups) -> tuple[int, int]:
+    """Per decode / chunk step: (layers that commit K/V rows in place,
+    layers that write back a whole layer state).  A self-attention layer
+    that also holds a cross-attention cache counts in both."""
+    inplace = writeback = 0
+    for spec in groups:
+        for kind, _ in spec.units:
+            own = _commits_in_place(cfg, kind)
+            inplace += spec.repeats * own
+            writeback += spec.repeats * (not own or cfg.cross_attn_decoder)
+    return inplace, writeback
+
+
+def _write_layer(pool, one, r):
+    return jax.tree_util.tree_map(
+        lambda a, o: jax.lax.dynamic_update_index_in_dim(
+            a, o.astype(a.dtype), r, 0), pool, one)
+
+
 def _apply_block(p_blk, x, cache_blk, *, cfg, spec, mode, pos, cross_src,
                  impl, causal, kv_cap=0, length=None, segments=None,
-                 kv_bits=0):
+                 kv_bits=0, layer=None):
+    """``layer`` (layer index): ``cache_blk`` is the group's stacked
+    pool and the returned cache is that pool with this layer's state
+    committed (see :func:`run_stack`)."""
     new_cache = {}
     aux_total = jnp.zeros((), jnp.float32)
     for ui, (kind, use_moe) in enumerate(spec.units):
         c_in = None if cache_blk is None else cache_blk.get(f"u{ui}")
+        # sub-caches that stay stacked and take their rows in place; every
+        # other one is sliced to this layer and written back whole
+        own = ("attn",) if layer is not None and _commits_in_place(cfg, kind) \
+            else ()
+        if layer is not None:
+            c_in = {n: c if n in own else layer_of(c, layer)
+                    for n, c in c_in.items()}
         x, c_out, aux = _apply_layer(
             p_blk[f"u{ui}"], x, cfg=cfg, kind=kind, use_moe=use_moe, mode=mode,
             pos=pos, cache=c_in, cross_src=cross_src, impl=impl, causal=causal,
-            kv_cap=kv_cap, length=length, segments=segments, kv_bits=kv_bits)
+            kv_cap=kv_cap, length=length, segments=segments, kv_bits=kv_bits,
+            layer=layer if own else None)
+        if layer is not None:
+            pool = cache_blk[f"u{ui}"]
+            c_out = {n: c_out[n] if n in own
+                     else _write_layer(pool[n], c_out[n], layer) for n in pool}
         new_cache[f"u{ui}"] = c_out
         aux_total = aux_total + aux
     return x, (new_cache if mode != "train" else None), aux_total
@@ -261,44 +315,53 @@ def _apply_block(p_blk, x, cache_blk, *, cfg, spec, mode, pos, cross_src,
 def run_stack(stack_params, x, *, cfg, groups, mode, pos, caches=None,
               cross_src=None, impl="auto", causal=True, remat=False,
               remat_policy: Optional[str] = None, kv_cap=0,
-              length=None, segments=None, kv_bits=0,
-              decode_unroll: int = 8):
-    """``decode_unroll``: decode-mode groups with at most this many repeats
-    run as an unrolled Python loop instead of ``lax.scan``.  Scan passes the
-    stacked KV pool through xs-slicing and ys-stacking — a full pool
-    read+write per token that buffer donation cannot alias away.  Unrolled,
-    the per-repeat update is a ``dynamic_update_slice`` on the stacked leaf,
-    so a donated cache is updated in place (decode graphs are S=1 and tiny,
-    so HLO growth is negligible; large-repeat configs keep scan to preserve
-    O(1)-in-depth HLO for the dry-run)."""
+              length=None, segments=None, kv_bits=0):
+    """Run the layer groups, each as one ``lax.scan`` over its repeats.
+
+    Decode and chunk modes with a cache scan over (group params, layer
+    index) and carry ``(x, pool)``: each self-attention layer scatters its
+    new K/V/pos rows into the stacked pool at ``[layer, row, slot]`` and
+    reads its own layer from it, so a donated pool is updated in place —
+    no per-layer write-back and no stacked copy of the pool after the
+    loop.  Other unit kinds (MLA, cross, SSM, recurrent) return their layer
+    state, written back into the carried pool with one
+    ``dynamic_update_index_in_dim`` (:func:`kv_commit_layers` counts
+    both).  Groups of at most :data:`DECODE_UNROLL` repeats run the same
+    layer body unrolled.  Train and prefill scan over the params alone,
+    prefill stacking the per-layer caches it builds as scan outputs."""
     new_caches = []
     aux_total = jnp.zeros((), jnp.float32)
     for gi, spec in enumerate(groups):
         gp = stack_params[gi]
         gc = None if caches is None else caches[gi]
 
-        if mode in ("decode", "chunk") and gc is not None and not remat \
-                and spec.repeats <= decode_unroll:
-            new_gc = gc
-            for r in range(spec.repeats):
-                p_blk = jax.tree_util.tree_map(lambda p, r=r: p[r], gp)
-                c_blk = jax.tree_util.tree_map(lambda c, r=r: c[r], gc)
-                x, c_out, _ = _apply_block(
-                    p_blk, x, c_blk, cfg=cfg, spec=spec, mode=mode, pos=pos,
+        if mode in ("decode", "chunk") and gc is not None:
+            def layer_step(carry, xs, spec=spec):
+                x, pool = carry
+                p_blk, r = xs
+                x, pool, _ = _apply_block(
+                    p_blk, x, pool, cfg=cfg, spec=spec, mode=mode, pos=pos,
                     cross_src=cross_src, impl=impl, causal=causal,
                     kv_cap=kv_cap, length=length, segments=segments,
-                    kv_bits=kv_bits)
-                new_gc = jax.tree_util.tree_map(
-                    lambda pool, one, r=r: pool.at[r].set(one.astype(pool.dtype)),
-                    new_gc, c_out)
-            new_caches.append(new_gc)
+                    kv_bits=kv_bits, layer=r)
+                return (x, pool), None
+
+            carry = (x, gc)
+            if spec.repeats <= DECODE_UNROLL:
+                for r in range(spec.repeats):
+                    p_blk = jax.tree_util.tree_map(lambda p, r=r: p[r], gp)
+                    carry, _ = layer_step(carry, (p_blk, r))
+            else:
+                carry, _ = jax.lax.scan(
+                    layer_step, carry,
+                    (gp, jnp.arange(spec.repeats, dtype=jnp.int32)))
+            x, gc = carry
+            new_caches.append(gc)
             continue
 
-        def step(carry, xs, spec=spec):
-            x = carry
-            p_blk, c_blk = xs
+        def step(x, p_blk, spec=spec):
             x, c_out, aux = _apply_block(
-                p_blk, x, c_blk, cfg=cfg, spec=spec, mode=mode, pos=pos,
+                p_blk, x, None, cfg=cfg, spec=spec, mode=mode, pos=pos,
                 cross_src=cross_src, impl=impl, causal=causal, kv_cap=kv_cap,
                 length=length, segments=segments, kv_bits=kv_bits)
             return x, (c_out, aux)
@@ -309,11 +372,7 @@ def run_stack(stack_params, x, *, cfg, groups, mode, pos, caches=None,
                 policy = jax.checkpoint_policies.dots_with_no_batch_dims_saveable
             step = jax.checkpoint(step, policy=policy)
 
-        if gc is None:
-            x, (c_stacked, aux) = jax.lax.scan(
-                lambda c, p: step(c, (p, None)), x, gp)
-        else:
-            x, (c_stacked, aux) = jax.lax.scan(step, x, (gp, gc))
+        x, (c_stacked, aux) = jax.lax.scan(step, x, gp)
         new_caches.append(c_stacked)
         aux_total = aux_total + jnp.sum(aux)
     return x, new_caches, aux_total

@@ -1154,9 +1154,13 @@ class ServingEngine:
         }
 
     def stats(self) -> dict:
+        # per decode / chunk step: layers whose K/V rows commit in place
+        # into the stacked pool, and layers that write back a whole state
+        commit = {"kv_rows_inplace_layers": self.executor.kv_rows_inplace_layers,
+                  "kv_writeback_layers": self.executor.kv_writeback_layers}
         done = self.finished
         if not done:
-            return {"finished": 0, **self._failure_stats()}
+            return {"finished": 0, **commit, **self._failure_stats()}
         lat = [r.t_done - r.t_enqueue for r in done]
         ttft = [r.t_first_token - r.t_enqueue for r in done]
         # per-token cadence after the first token (needs >= 2 tokens);
@@ -1263,6 +1267,7 @@ class ServingEngine:
             # {n_active_slots: decode iterations at that occupancy} — the
             # measured continuous-batching utilisation of the slot pool
             "active_slots_hist": dict(sorted(self.active_slot_hist.items())),
+            **commit,
             **spec,
             **trace,
             **self._failure_stats(),

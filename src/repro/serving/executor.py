@@ -51,6 +51,11 @@ class Executor:
                                              or min(128, ecfg.kv_len),
                                              ecfg.kv_len))
 
+        # layers per decode / chunk step that commit K/V rows in place, and
+        # that write back a whole layer state (models.transformer.run_stack)
+        self.kv_rows_inplace_layers, self.kv_writeback_layers = \
+            T.kv_commit_layers(cfg, T.build_groups(cfg))
+
         # host-transfer accounting (benchmarks/perf_serving.py)
         self.host_transfers = 0
         self.host_bytes = 0

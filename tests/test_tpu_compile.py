@@ -129,13 +129,16 @@ def test_kernel_compiles_for_v5e(one_chip, case):
     assert _kernels(text) == {KERNEL[case]}
 
 
+@pytest.mark.parametrize("layers", [2, 12])
 def test_sharded_decode_step_compiles_for_v5e_2x2(topo, one_chip,
-                                                  monkeypatch):
+                                                  monkeypatch, layers):
     """The engine's fused decode step with ``impl="flash"`` on a (data=2,
     model=2) mesh of described chips, at qwen2.5-3b widths cut to two
-    layers.  GSPMD refuses to partition a Mosaic kernel, so the kernel
-    must reach the compiler inside a shard_map, with the KV pool local to
-    each device (no all-gather of it)."""
+    layers (an unrolled layer loop) or twelve (a layer scan).  GSPMD
+    refuses to partition a Mosaic kernel, so the kernel must reach the
+    compiler inside a shard_map, with the KV pool local to each device (no
+    all-gather of it); each layer's rows commit in place into the sharded
+    stacked pool, so no device copies or update-slices its whole shard."""
     import dataclasses
 
     import numpy as np
@@ -149,7 +152,7 @@ def test_sharded_decode_step_compiles_for_v5e_2x2(topo, one_chip,
 
     # ops picks compiled kernels for impl="flash" only on a TPU backend
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    cfg = dataclasses.replace(get_config("qwen2.5-3b"), n_layers=2)
+    cfg = dataclasses.replace(get_config("qwen2.5-3b"), n_layers=layers)
     mesh = Mesh(np.asarray(topo.devices).reshape(2, 2), ("data", "model"))
     ex = Executor(cfg, None, EngineConfig(max_batch=B, kv_len=SKV,
                                           impl="flash"), mesh=mesh)
@@ -180,3 +183,78 @@ def test_sharded_decode_step_compiles_for_v5e_2x2(topo, one_chip,
             for dims in re.findall(r"\[([\d,]*)\]", result):
                 n = np.prod([int(d) for d in dims.split(",") if d])
                 assert n < k_elems // 8, line
+    # batch over data, KV heads over model: each device holds a quarter
+    assert cache_shardings(cache, ex.shard_ctx)["stack"][0]["u0"]["attn"][
+        "k"].spec == P(None, "data", None, "model", None)
+    assert not _whole_pool_moves(text, (layers, B // 2, SKV, HKV // 2, HD))
+
+
+@pytest.mark.parametrize("program", ["fused_step", "chunk_step"])
+def test_layer_scan_commits_kv_in_place_for_v5e(one_chip, monkeypatch,
+                                                program):
+    """The fused decode step and the chunk step at qwen2.5-3b widths cut to
+    12 layers, compiled for one described chip: the layer scan carries the
+    donated KV pool and commits each layer's rows into it, so no copy or
+    update-slice of the whole stacked pool is in the program, and its
+    temporaries stay below one layer's K pool."""
+    import dataclasses
+
+    from repro.config import get_config
+    from repro.models import transformer as T
+    from repro.serving.engine import EngineConfig
+    from repro.serving.executor import Executor
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    layers = 12
+    cfg = dataclasses.replace(get_config("qwen2.5-3b"), n_layers=layers)
+    ex = Executor(cfg, None, EngineConfig(max_batch=B, kv_len=SKV,
+                                          impl="flash"))
+
+    def placed(tree):
+        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=one_chip), tree)
+
+    params = placed(jax.eval_shape(lambda: T.init_params(
+        cfg, jax.random.PRNGKey(0), param_dtype=jnp.bfloat16)))
+    cache = placed(jax.eval_shape(lambda: T.init_cache(cfg, B, SKV)))
+    vec = jax.ShapeDtypeStruct((B,), jnp.int32, sharding=one_chip)
+    state = {"tokens": vec, "pos": vec, "budget": vec,
+             "live": jax.ShapeDtypeStruct((B,), bool, sharding=one_chip),
+             "key": placed(jax.eval_shape(lambda: jax.random.PRNGKey(0)))}
+    if program == "fused_step":
+        lowered = ex.jit_step.lower(params, cache, state)
+    else:
+        blk = jax.ShapeDtypeStruct((B, C), jnp.int32, sharding=one_chip)
+        lowered = ex.jit_chunk_step.lower(
+            params, cache, state, blk, blk, vec,
+            jax.ShapeDtypeStruct((B,), bool, sharding=one_chip), vec)
+    compiled = lowered.compile()
+    text = compiled.as_text()
+
+    assert not _whole_pool_moves(text, (layers, B, SKV, HKV, HD))
+    k_layer_bytes = B * SKV * HKV * HD * 2
+    assert compiled.memory_analysis().temp_size_in_bytes < k_layer_bytes
+
+
+def _whole_pool_moves(text: str, pool: tuple) -> list:
+    """Instructions of a compiled program that copy the stacked pool or
+    update a slice of it into a whole new pool-shaped result: a ``copy``, a
+    ``dynamic-update-slice``, or a fusion that holds one.  Shapes compare
+    with their unit dims dropped (a bitcast may drop them)."""
+    def dims(shape):
+        return tuple(int(d) for d in shape.split(",") if d and d != "1")
+
+    comps = dict(re.findall(r"^(%\S+) .*?\{\n(.*?)\n\}$", text, re.M | re.S))
+    op = re.compile(r"=\s*\w+\[([\d,]*)\]\S*\s+([\w-]+)\((.*)")
+    moves = []
+    for line in text.splitlines():
+        m = op.search(line)
+        if not m or dims(m.group(1)) != dims(",".join(map(str, pool))):
+            continue
+        called = re.search(r"calls=(%[\w.-]+)", m.group(3))
+        body = comps.get(called.group(1), "") if called else ""
+        if m.group(2) in ("copy", "dynamic-update-slice") or (
+                m.group(2) == "fusion" and "dynamic-update-slice" in
+                line.split("=", 1)[0] + body):
+            moves.append(line)
+    return moves
