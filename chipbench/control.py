@@ -38,23 +38,23 @@ sys.path.insert(0, str(ROOT / "chipbench"))
 from harness import spec  # noqa: E402
 
 
-def readings(root, bench, cell, seed, seconds, controls=()):
+def readings(root, bench, cell, seed, seconds, devices, controls=()):
     import functools
 
     from harness import cell as cellrun
     from harness import driver, reference
     from harness.traffic import RequestStream
 
-    conf, mix, rate, m, eng, w, engine = cellrun.prepare(
-        root, bench, cell, seed)
-    stream = RequestStream(mix, seed, m["vocab_size"], rate)
-    run = driver.drive(engine, stream, mix, seconds)
+    setup = cellrun.prepare(root, bench, cell, seed, devices)
+    mix, m = setup.mix, setup.m
+    stream = RequestStream(mix, seed, m["vocab_size"], setup.rate)
+    run = driver.drive(setup.engine, stream, mix, seconds)
     att = [r for r in run.recs if run.w0 <= r.due < run.w1]
     failed = sum(1 for r in att if r.first is None)
-    cellrun._free(engine)
-    del engine
-    args = (w, m, run.recs, int(mix["check_requests"]), seed,
-            conf["check"]["max_logit_gap"], failed,
+    cellrun._free(setup.engine)
+    setup.engine = None
+    args = (setup.fam, setup.w, m, run.recs, int(mix["check_requests"]),
+            seed, setup.conf["check"]["max_logit_gap"], failed,
             cellrun.stalled(run, float(mix["stall_s"])))
     ok, chk = cellrun.check(*args)
     out = {"seed": seed, "correct": ok,
@@ -69,7 +69,7 @@ def readings(root, bench, cell, seed, seconds, controls=()):
             reference.control_gaps, quant=q))
         out[f"control_{q}"] = {"correct": ok,
                                "max_logit_gap": chk["max_logit_gap"]["value"]}
-    del w
+    del setup, args
     gc.collect()
     return out
 
@@ -86,8 +86,9 @@ def main(argv=None) -> int:
 
     import jax
 
-    if jax.devices()[0].platform != "tpu":
-        print("control: no TPU; not running", file=sys.stderr)
+    devices = jax.devices()
+    if devices[0].platform != "tpu" or len(devices) < cell["chips"]:
+        print("control: not enough TPU chips; not running", file=sys.stderr)
         return 2
     import run as runmod
 
@@ -95,6 +96,7 @@ def main(argv=None) -> int:
     ctl = [int(s) for s in args.control_seeds.split(",") if s]
     for s in (int(s) for s in args.seeds.split(",")):
         r = readings(ROOT, bench, cell, s, args.seconds,
+                     devices[:cell["chips"]],
                      controls=("int8",) if s in ctl else ())
         print(json.dumps(r), flush=True)
     print(f"control: {time.monotonic() - T_START:.1f} s", file=sys.stderr)

@@ -16,18 +16,11 @@ from harness import driver, readers, reference, roofline, spec, weights
 from harness.clock import CompileClock
 from harness.stats import percentile
 
-ARCH_KEYS = {                 # configuration file key -> program ModelConfig
-    "num_hidden_layers": "n_layers", "hidden_size": "d_model",
-    "intermediate_size": "d_ff", "num_attention_heads": "n_heads",
-    "num_key_value_heads": "n_kv_heads", "head_dim": "head_dim",
-    "vocab_size": "vocab_size", "rope_theta": "rope_theta",
-    "qkv_bias": "qkv_bias", "tie_word_embeddings": "tie_embeddings",
-}
 
-
-def program_config(root: Path, conf: dict):
-    """The program's ``ModelConfig`` for ``conf``, checked against the
-    configuration file's model block, so the file states what runs."""
+def program_config(root: Path, conf: dict, fam):
+    """The program's ``ModelConfig`` for ``conf``, checked by the family
+    against the configuration file's model block, so the file states what
+    runs."""
     src = str(root / "src")
     if src not in sys.path:
         sys.path.insert(0, src)
@@ -36,28 +29,21 @@ def program_config(root: Path, conf: dict):
 
     cfg = dataclasses.replace(get_config(conf["arch"]),
                               **conf["arch_overrides"])
-    m = conf["model"]
-    want = {v: m[k] for k, v in ARCH_KEYS.items()}
-    want["parallel_block"] = m["block"] == "parallel"
-    want["glu"] = m["mlp"] == "silu_glu"
-    want["act"] = "silu" if m["mlp"] == "silu_glu" else "gelu"
-    want["norm"] = m["norm"]
-    got = {k: getattr(cfg, k) for k in want}
-    if (got != want or m["rotary_dim"] != m["head_dim"]
-            or cfg.v_head_dim != m["head_dim"] or cfg.pattern != ("global",)):
-        raise ValueError(f"{conf['name']}: the program's {conf['arch']} is "
-                         f"{got}, pattern {cfg.pattern}; the file states "
-                         f"{want}")
+    try:
+        fam.program_keys(conf["model"], cfg)
+    except ValueError as e:
+        raise ValueError(f"{conf['name']}: {conf['arch']}: {e}") from None
     return cfg
 
 
-def build_engine(cfg, w: dict, m: dict, engine: dict, seed: int):
+def build_engine(cfg, fam, w: dict, m: dict, engine: dict, seed: int,
+                 mesh=None):
     import jax
     import jax.numpy as jnp
     from repro.models import transformer as T
     from repro.serving.engine import EngineConfig, ServingEngine
 
-    params = weights.program_tree(w, m)
+    params = fam.program_tree(w, m)
     want = jax.eval_shape(
         lambda k: T.init_params(cfg, k, param_dtype=jnp.bfloat16),
         jax.ShapeDtypeStruct((2,), jnp.uint32))
@@ -68,7 +54,7 @@ def build_engine(cfg, w: dict, m: dict, engine: dict, seed: int):
         raise ValueError("the benchmark's weights do not match the "
                          "program's parameter tree")
     return ServingEngine(cfg, params, EngineConfig(
-        **engine, seed=seed % (2 ** 31 - 1)))
+        **engine, seed=seed % (2 ** 31 - 1)), mesh=mesh)
 
 
 def _free(engine):
@@ -80,13 +66,15 @@ def _free(engine):
         x.delete()
 
 
+def _peaks(devices) -> list:
+    return [(x.memory_stats() or {}).get("peak_bytes_in_use", 0)
+            for x in devices]
+
+
 def _device(devices, traced, tr=None):
     d = devices[0]
     out = {"platform": d.platform, "kind": d.device_kind,
-           "count": len(devices),
-           "memory_peak_bytes": max(
-               (x.memory_stats() or {}).get("peak_bytes_in_use", 0)
-               for x in devices)}
+           "count": len(devices), "memory_peak_bytes": max(_peaks(devices))}
     if traced and tr is not None:
         out["busy_s"], out["window_s"] = tr
     return out
@@ -141,7 +129,7 @@ def stalled(run: driver.Run, stall_s: float) -> int:
                and run.last_step - r.times[-1] > stall_s)
 
 
-def check(w: dict, m: dict, recs: list, k: int, seed: int,
+def check(fam, w: dict, m: dict, recs: list, k: int, seed: int,
           limit: Optional[float], failed: int, stuck: int,
           gaps_of: Callable = reference.gaps) -> tuple[bool, dict]:
     """Reference gaps of the sampled requests' served tokens, against the
@@ -149,7 +137,7 @@ def check(w: dict, m: dict, recs: list, k: int, seed: int,
     request in flight may have stopped getting tokens.  ``gaps_of`` is
     the reading; a control puts its own in the program's place."""
     got = sample(recs, k, seed)
-    gaps = [gaps_of(w, m, r.req.prompt, r.req.output) for r in got]
+    gaps = [gaps_of(fam, w, m, r.req.prompt, r.req.output) for r in got]
     n = int(sum(len(g) for g in gaps))
     gap = float(max((g.max() for g in gaps), default=float("nan")))
     ok = (n > 0 and limit is not None and gap <= limit and failed == 0
@@ -162,23 +150,48 @@ def check(w: dict, m: dict, recs: list, k: int, seed: int,
     }
 
 
-def prepare(root: Path, bench: dict, cell: dict, seed: int):
-    """Everything a run sets up before traffic: the configuration, mix and
-    rate; the weights from ``seed``; the engine with the programs this
-    cell's traffic runs compiled (or read from the cache)."""
+@dataclasses.dataclass
+class Setup:
+    """What a run sets up before traffic."""
+    conf: dict                     # the configuration file
+    mix: dict                      # the traffic mix
+    rate: Optional[float]          # an open-loop cell's offered rate
+    fam: object                    # the family file's module
+    cfg: object                    # the program's ModelConfig
+    mesh: object                   # the configuration's mesh, or None
+    w: dict                        # the weights, the benchmark's layout
+    engine: object                 # the program's ServingEngine
+
+    @property
+    def m(self) -> dict:
+        return self.conf["model"]
+
+    def new_engine(self, seed: int):
+        return build_engine(self.cfg, self.fam, self.w, self.m,
+                            self.conf["engine"], seed, self.mesh)
+
+
+def prepare(root: Path, bench: dict, cell: dict, seed: int,
+            devices: list) -> Setup:
+    """Everything a run sets up before traffic: the configuration, its
+    family, mix and rate; the weights from ``seed``, over the
+    configuration's mesh of ``devices`` where it states one; the engine
+    with the programs this cell's traffic runs compiled (or read from the
+    cache)."""
     import jax
 
-    conf = spec.config(root, bench, cell["config"])
+    conf, fam = spec.check(root, bench, cell)
     mix = spec.traffic(root, cell["traffic"])
     rate = spec.rate(root, cell["name"])["rate_rps"] \
         if mix["loop"] == "open" else None
-    m, eng = conf["model"], conf["engine"]
-    cfg = program_config(root, conf)
-    w = weights.make(m, seed)
+    cfg = program_config(root, conf, fam)
+    mesh = weights.mesh(conf, devices)
+    w = weights.make(fam, conf["model"], seed, mesh)
     jax.block_until_ready(w)
-    engine = build_engine(cfg, w, m, eng, seed)
-    driver.warm(engine, mix["prompt"]["max"] > engine._chunk)
-    return conf, mix, rate, m, eng, w, engine
+    s = Setup(conf, mix, rate, fam, cfg, mesh, w, None)
+    s.engine = s.new_engine(seed)
+    driver.warm(s.engine, mix["prompt"]["max"] > s.engine._chunk)
+    return s
 
 
 def run(root: Path, bench: dict, cell: dict, *, seed: int, seconds: float,
@@ -189,7 +202,8 @@ def run(root: Path, bench: dict, cell: dict, *, seed: int, seconds: float,
     import jax
 
     clock = CompileClock()
-    conf, mix, rate, m, eng, w, engine = prepare(root, bench, cell, seed)
+    setup = prepare(root, bench, cell, seed, devices)
+    conf, mix, m, engine = setup.conf, setup.mix, setup.m, setup.engine
     pk = roofline.peaks(devices[0].device_kind) \
         if devices[0].platform == "tpu" else None
     calls = None
@@ -207,7 +221,7 @@ def run(root: Path, bench: dict, cell: dict, *, seed: int, seconds: float,
         patch(engine)
     from harness.traffic import RequestStream
 
-    stream = RequestStream(mix, seed, m["vocab_size"], rate)
+    stream = RequestStream(mix, seed, m["vocab_size"], setup.rate)
     marks = {}
 
     def on_window():
@@ -244,12 +258,12 @@ def run(root: Path, bench: dict, cell: dict, *, seed: int, seconds: float,
               f"{late[-1] * 1e3:.3f} ms over {len(late)} submissions",
               file=sys.stderr)
     print(f"attempted {len(att)}, failed {failed}; samples {e2e['_counts']}; "
-          f"peak device memory {device['memory_peak_bytes']} B",
-          file=sys.stderr, flush=True)
+          f"peak device memory {device['memory_peak_bytes']} B, by chip "
+          f"{_peaks(devices)}", file=sys.stderr, flush=True)
 
     metrics = {}
-    ctx = readers.Context(m=m, shapes=readers.shapes(engine), pk=pk,
-                          run=run_, calls=calls, trace=tr)
+    ctx = readers.Context(m=m, fam=setup.fam, shapes=readers.shapes(engine),
+                          pk=pk, run=run_, calls=calls, trace=tr)
     for spec_m in spec.metrics(bench, cell["name"], traced):
         name = spec_m["name"]
         if traced:
@@ -267,7 +281,9 @@ def run(root: Path, bench: dict, cell: dict, *, seed: int, seconds: float,
 
     _free(engine)
     del engine
-    ok, checks = check(w, m, run_.recs, int(mix["check_requests"]), seed,
+    setup.engine = None
+    ok, checks = check(setup.fam, setup.w, m, run_.recs,
+                       int(mix["check_requests"]), seed,
                        conf["check"]["max_logit_gap"], failed,
                        stalled(run_, float(mix["stall_s"])))
     result = {"correct": ok, "attempted": len(att), "failed": failed,

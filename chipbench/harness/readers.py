@@ -5,6 +5,12 @@ the run gives it nothing to read (no such program or kernel in the trace,
 no prompt token in the traced slice); a run fails where a metric declared
 for its cell reads ``None``.  A reader never returns 0 for a share of a
 roofline or of a peak in place of a missing reading.
+
+On a mesh the trace holds one device plane a chip.  A program's or a
+kernel's seconds are summed over the chips (chip-seconds), so a share of
+one chip's peak or roofline over them is the share of all the chips'; a
+time per step or per token is per chip; the traced slice counts once a
+chip.
 """
 from __future__ import annotations
 
@@ -27,6 +33,7 @@ def shapes(engine) -> dict:
 @dataclasses.dataclass
 class Context:
     m: dict                        # the configuration's model block
+    fam: object                    # its family file's module
     shapes: dict                   # shapes(engine)
     pk: dict                       # chip peaks
     run: object                    # driver.Run
@@ -78,8 +85,10 @@ def decode_step_ms(ctx: Context):
 
 
 def prefill_ms_per_ktok(ctx: Context):
+    """Device time a chip spends in the prefill programs per 1,000 prompt
+    tokens."""
     secs = [ctx.module(k) for k in (PACKED, CHUNK)]
-    secs = sum(s[0] for s in secs if s)
+    secs = sum(s[0] for s in secs if s) / ctx.trace.n_chips
     a, b = ctx.run.prefill_tokens
     if b <= a or not secs:
         return None
@@ -124,14 +133,15 @@ def _mfu(flops: float, seconds: float, pk: dict):
 
 def _decode_flops(ctx: Context) -> float:
     live = ctx.calls.decode_live
-    return roofline.model_flops(ctx.m, [(n - 1, 1) for s in live for n in s],
+    return roofline.model_flops(ctx.fam, ctx.m,
+                                [(n - 1, 1) for s in live for n in s],
                                 sum(len(s) for s in live))
 
 
 def _prefill_flops(ctx: Context) -> float:
     c = ctx.calls
     pieces = [p for call in c.prefill_pieces + c.chunk_pieces for p in call]
-    return roofline.model_flops(ctx.m, pieces, c.final_rows)
+    return roofline.model_flops(ctx.fam, ctx.m, pieces, c.final_rows)
 
 
 def step_mfu_pct(ctx: Context):
@@ -151,10 +161,11 @@ def prefill_mfu_pct(ctx: Context):
 
 
 def slice_mfu_pct(ctx: Context):
-    """The chip's share of its peak over the whole traced slice: model
-    FLOPs of every prompt and output token processed, over the slice."""
-    return _mfu(_decode_flops(ctx) + _prefill_flops(ctx), ctx.window_s,
-                ctx.pk)
+    """The chips' share of their peak over the whole traced slice: model
+    FLOPs of every prompt and output token processed, over the slice on
+    every chip."""
+    return _mfu(_decode_flops(ctx) + _prefill_flops(ctx),
+                ctx.window_s * ctx.trace.n_chips, ctx.pk)
 
 
 def idle_pct(ctx: Context):

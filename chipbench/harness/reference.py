@@ -4,6 +4,11 @@ the highest matmul precision, written from the configuration file alone.
 It imports nothing of the program.  It runs one sequence at a time, layer
 by layer (one compiled layer function, the layer index an argument), so
 that it fits beside the weights once the program's KV pool is freed.
+This file holds what every family shares: the padding, the embedding,
+the layer loop, the final norm, logits in row blocks and the readings.
+The layer body and the matrices a control rounds are the family's
+(``chipbench/models/<family>.py``), which may use ``_mm``, ``_norm`` and
+``_rope`` from here.
 
 ``gaps`` is the comparison that decides ``correct``: for every served
 token, how far the reference's logit of that token lies below the
@@ -26,8 +31,6 @@ import numpy as np
 HIGHEST = jax.lax.Precision.HIGHEST
 PAD = 256            # sequences are padded to a multiple of this length
 ROWS = 256           # rows of logits computed at once
-
-MATRICES = ("wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down")
 
 
 def _mm(a, b):
@@ -67,49 +70,22 @@ def _rope(x, pos, theta, rot):
                             x[..., rot:]], axis=-1)
 
 
-def _mlp(h, lw, m):
-    if m["mlp"] == "silu_glu":
-        a = jax.nn.silu(_mm(h, lw["w_gate"])) * _mm(h, lw["w_up"])
-    elif m["mlp"] == "gelu_tanh":
-        a = jax.nn.gelu(_mm(h, lw["w_up"]), approximate=True)
-    else:
-        raise ValueError(f"unknown mlp {m['mlp']!r}")
-    return _mm(a, lw["w_down"])
+# weights outside the layer stack, read by the scaffolding here
+OUTSIDE = ("embed", "lm_head", "final_norm")
 
 
-@functools.partial(jax.jit, static_argnames=("mkey", "quant"))
-def _layer(x, w, l, *, mkey, quant):
-    m = dict(mkey)
-    S = x.shape[0]
-    Hq, Hkv, hd = (m["num_attention_heads"], m["num_key_value_heads"],
-                   m["head_dim"])
+@functools.partial(jax.jit, static_argnames=("fam", "mkey", "quant"))
+def _layer(x, w, l, *, fam, mkey, quant):
+    """Layer ``l`` of the family's reference: its weights sliced from the
+    stack in f32, the family's ``MATRICES`` rounded for a control."""
     lw = {}
     for name in w:
-        if name in ("embed", "lm_head", "final_norm"):
+        if name in OUTSIDE:
             continue
         a = jax.lax.dynamic_index_in_dim(w[name], l, keepdims=False)
         a = a.astype(jnp.float32)
-        lw[name] = _lower(a, quant) if name in MATRICES else a
-    eps = m["norm_eps"]
-    pos = jnp.arange(S)
-    h = _norm(x, lw["ln1"], eps)
-    q, k, v = _mm(h, lw["wq"]), _mm(h, lw["wk"]), _mm(h, lw["wv"])
-    if m["qkv_bias"]:
-        q, k, v = q + lw["bq"], k + lw["bk"], v + lw["bv"]
-    q = _rope(q.reshape(S, Hq, hd), pos, m["rope_theta"], m["rotary_dim"])
-    k = _rope(k.reshape(S, Hkv, hd), pos, m["rope_theta"], m["rotary_dim"])
-    v = v.reshape(S, Hkv, hd)
-    q = q.reshape(S, Hkv, Hq // Hkv, hd)
-    s = jnp.einsum("qgrd,kgd->grqk", q, k, precision=HIGHEST) * hd ** -0.5
-    s = jnp.where(pos[None, None, None, :] <= pos[None, None, :, None], s,
-                  -jnp.inf)
-    p = jax.nn.softmax(s, axis=-1)
-    o = jnp.einsum("grqk,kgd->qgrd", p, v, precision=HIGHEST)
-    a = _mm(o.reshape(S, Hq * hd), lw["wo"])
-    if m["block"] == "parallel":
-        return x + a + _mlp(h, lw, m)
-    x = x + a
-    return x + _mlp(_norm(x, lw["ln2"], eps), lw, m)
+        lw[name] = _lower(a, quant) if name in fam.MATRICES else a
+    return fam.layer(x, lw, jnp.arange(x.shape[0]), dict(mkey))
 
 
 @functools.partial(jax.jit, static_argnames=("mkey",))
@@ -129,7 +105,7 @@ def _key(m):
                         if not isinstance(v, (list, dict))))
 
 
-def hidden(w: dict, m: dict, seq: np.ndarray, rows: np.ndarray,
+def hidden(fam, w: dict, m: dict, seq: np.ndarray, rows: np.ndarray,
            quant=None) -> jax.Array:
     """Final-normed hidden states at ``rows`` of ``seq`` (f32)."""
     S = len(seq)
@@ -139,7 +115,7 @@ def hidden(w: dict, m: dict, seq: np.ndarray, rows: np.ndarray,
     mkey = _key(m)
     x = _embed(w, jnp.asarray(toks), mkey=mkey)
     for l in range(m["num_hidden_layers"]):
-        x = _layer(x, w, jnp.int32(l), mkey=mkey, quant=quant)
+        x = _layer(x, w, jnp.int32(l), fam=fam, mkey=mkey, quant=quant)
     return _final(w, x, jnp.asarray(rows, jnp.int32), mkey=mkey)
 
 
@@ -175,11 +151,11 @@ def _served(prompt, served):
     return seq, rows
 
 
-def gaps(w: dict, m: dict, prompt, served) -> np.ndarray:
+def gaps(fam, w: dict, m: dict, prompt, served) -> np.ndarray:
     """Per served token: the reference's best logit minus its logit of
     that token, at the position that chose it."""
     seq, rows = _served(prompt, served)
-    h = hidden(w, m, seq, rows)
+    h = hidden(fam, w, m, seq, rows)
     toks = np.asarray(served, np.int32)
     mkey = _key(m)
     out = [np.asarray(_gap_at(w, hb, jnp.asarray(toks[i:i + hb.shape[0]]),
@@ -187,14 +163,15 @@ def gaps(w: dict, m: dict, prompt, served) -> np.ndarray:
     return np.concatenate(out)
 
 
-def control_gaps(w: dict, m: dict, prompt, served, quant: str) -> np.ndarray:
+def control_gaps(fam, w: dict, m: dict, prompt, served,
+                 quant: str) -> np.ndarray:
     """At each position of the same prompt and served tokens, the gap (in
     the f32 reference) of the token that the ``quant`` reference puts
     first."""
     seq, rows = _served(prompt, served)
     mkey = _key(m)
-    h_ref = hidden(w, m, seq, rows)
-    h_low = hidden(w, m, seq, rows, quant=quant)
+    h_ref = hidden(fam, w, m, seq, rows)
+    h_low = hidden(fam, w, m, seq, rows, quant=quant)
     out = []
     for (i, hr), (_, hl) in zip(_blocks(h_ref), _blocks(h_low)):
         pick = jnp.argmax(_logits(w, hl, mkey=mkey, quant=quant), axis=1)

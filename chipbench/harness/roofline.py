@@ -76,27 +76,19 @@ def prefill_attention(segments: Iterable[tuple[int, int]], m: dict,
     return flops, nbytes
 
 
-# -- the dense model step ----------------------------------------------------
+# -- the model step -------------------------------------------------------
 
-def matmul_params_per_layer(m: dict) -> int:
-    d, f = m["hidden_size"], m["intermediate_size"]
-    hq, hkv = m["num_attention_heads"], m["num_key_value_heads"]
-    hd = m["head_dim"]
-    attn = d * (hq * hd + 2 * hkv * hd) + hq * hd * d
-    mlp = d * f * (3 if m["mlp"] == "silu_glu" else 2)
-    return attn + mlp
-
-
-def model_flops(m: dict, tokens: Iterable[tuple[int, int]],
+def model_flops(fam, m: dict, tokens: Iterable[tuple[int, int]],
                 logits_rows: int) -> float:
     """FLOPs the model needs for prompt or output tokens given as pieces
     ``(start, count)`` of one sequence each (positions ``start ..
     start+count-1``), with ``logits_rows`` rows sent through the output
-    head (one per sampled token).  Each token: two FLOPs a weight of every
-    layer's projections, and attention to itself and every earlier
-    position."""
+    head (one per sampled token).  Each token: two FLOPs a weight that the
+    family says one token uses in each layer
+    (``fam.matmul_params_per_layer``), and attention to itself and every
+    earlier position."""
     L = m["num_hidden_layers"]
-    per_tok = 2.0 * matmul_params_per_layer(m) * L
+    per_tok = 2.0 * fam.matmul_params_per_layer(m) * L
     hq, hd = m["num_attention_heads"], m["head_dim"]
     total = 0.0
     for start, count in tokens:

@@ -12,6 +12,13 @@ the seed.  The highest value a block offers is the distribution's
 ``1 - 1 / (2 * block)`` quantile; a larger block reaches further into the
 tail.
 
+A mix may fix that order too, with ``sizes_seed``: sizes (and an open
+loop's gaps) are then drawn from it and not from the run's seed, so every
+run offers the same sequence of sizes, and the run's seed draws only the
+token ids.  A closed loop whose window holds about one block needs it:
+there the order decides which sizes fall inside the window, and so how
+much work a run does.
+
 The Poisson arithmetic follows ``repro.serving.workload`` (exponential
 inter-arrival gaps at the offered rate); it is copied here so that a change
 to the program cannot move the yardstick.
@@ -103,7 +110,7 @@ class RequestStream:
             raise ValueError("an open loop needs a rate")
         self.mix, self.vocab, self.rate = mix, vocab, rate_rps
         self.block = int(mix.get("block", 16))
-        self._rng = np.random.default_rng([seed, 7])
+        self._rng = np.random.default_rng([mix.get("sizes_seed", seed), 7])
         self._tok = np.random.default_rng([seed, 11])
         self._buf: list[Request] = []
         self._t = 0.0

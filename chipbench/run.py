@@ -1,13 +1,18 @@
-"""Chip benchmark: one cell of ``BENCHMARK.json``, one process, one chip.
+"""Chip benchmark: one cell of ``BENCHMARK.json`` in one process, on the
+chips the cell asks for.
 
     python3 chipbench/run.py --workload <cell> --seed <n> --seconds <s> \
         --trace <0|1>
 
-Loads the cell's configuration, makes its weights from the seed on the
-device, warms the programs its traffic runs, offers its traffic for the
-mix's warm-up and then measures for ``--seconds``.  With ``--trace 1`` the
-profiler records a steady slice in the middle of the window, and the run
-reports the cell's per-layer metrics in place of its end-to-end ones.
+Loads the cell's configuration and its model family
+(``chipbench/models/<family>.py``), and checks that the configuration's
+mesh spans the cell's chips (no mesh: one chip).  Makes the weights from
+the seed on the chip, or replicated over the mesh, builds the engine on
+that mesh, warms the programs its traffic runs, offers its traffic for
+the mix's warm-up and then measures for ``--seconds``.  With
+``--trace 1`` the profiler records a steady slice in the middle of the
+window, and the run reports the cell's per-layer metrics in place of its
+end-to-end ones.
 
 Once the window has closed and the program's KV pool is freed, a sample of
 the finished requests, drawn from the seed with the longest among them, is
@@ -70,6 +75,7 @@ def main(argv=None) -> int:
     args = parse_args(argv)
     bench = spec.load(ROOT)
     cell = spec.cell(bench, args.workload)
+    spec.check(ROOT, bench, cell)
 
     import jax
 

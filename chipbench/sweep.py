@@ -40,8 +40,9 @@ def main(argv=None) -> int:
 
     import jax
 
-    if jax.devices()[0].platform != "tpu":
-        print("sweep: no TPU; not running", file=sys.stderr)
+    devices = jax.devices()
+    if devices[0].platform != "tpu" or len(devices) < cell["chips"]:
+        print("sweep: not enough TPU chips; not running", file=sys.stderr)
         return 2
     import run as runmod
     from harness import cell as cellrun
@@ -50,9 +51,9 @@ def main(argv=None) -> int:
     from harness.traffic import RequestStream
 
     runmod.enable_compile_cache()
-    conf, mix, _, m, eng, w, engine = cellrun.prepare(ROOT, bench, cell,
-                                                      args.seed)
-    cfg = cellrun.program_config(ROOT, conf)
+    setup = cellrun.prepare(ROOT, bench, cell, args.seed,
+                            devices[:cell["chips"]])
+    mix, m, engine = setup.mix, setup.m, setup.engine
     if mix["loop"] != "open":
         raise SystemExit("sweep: only open-loop cells have a rate")
     rows = []
@@ -79,7 +80,7 @@ def main(argv=None) -> int:
         rows.append(row)
         print(json.dumps(row), flush=True)
         cellrun._free(engine)           # a fresh pool for the next rate
-        engine = cellrun.build_engine(cfg, w, m, eng, args.seed)
+        engine = setup.engine = setup.new_engine(args.seed)
         driver.warm(engine, mix["prompt"]["max"] > engine._chunk)
     ok = [r["rate_rps"] for r in rows if r["sustained"]]
     knee = max(ok) if ok else None

@@ -1,8 +1,9 @@
 """The benchmark's own tests: ``python -m pytest chipbench/tests``.
 
 They run on the CPU at a tiny size.  A tiny checkout is laid out in a
-temporary directory: the real harness and metric readers, and a tiny
-configuration and traffic mix, found by name as a cell's would be."""
+temporary directory: the real harness, metric readers and model families,
+and a tiny configuration, its test-only family and traffic mixes, found by
+name as a cell's would be."""
 import json
 import shutil
 import sys
@@ -23,6 +24,8 @@ def tiny_root(tmp_path_factory):
     cb = root / "chipbench"
     cb.mkdir()
     shutil.copytree(REPO / "chipbench" / "metrics", cb / "metrics")
+    shutil.copytree(REPO / "chipbench" / "models", cb / "models")
+    shutil.copy(DATA / "tiny-dense.py", cb / "models" / "tiny-dense.py")
     (cb / "configs").mkdir()
     (cb / "traffic" / "rates").mkdir(parents=True)
     shutil.copy(DATA / "tiny.json", cb / "configs" / "tiny.json")

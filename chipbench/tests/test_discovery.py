@@ -16,9 +16,10 @@ def bench():
 
 def test_cells_find_their_files_by_name(bench):
     for cell in bench["workloads"]:
-        conf = spec.config(REPO, bench, cell["config"])
+        conf, fam = spec.check(REPO, bench, cell)  # family file, mesh size
         mix = spec.traffic(REPO, cell["traffic"])
         assert conf["name"] == cell["config"]
+        assert callable(fam.layer) and fam.MATRICES
         if mix["loop"] == "open":
             assert spec.rate(REPO, cell["name"])["rate_rps"] > 0
 

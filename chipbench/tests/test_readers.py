@@ -1,13 +1,15 @@
 """The per-layer readers' arithmetic on a hand-made run and trace."""
 import pytest
 
-from harness import driver, readers, roofline, xplane
+from conftest import REPO
+from harness import driver, readers, roofline, spec, xplane
 
 M = {"num_hidden_layers": 2, "hidden_size": 64, "intermediate_size": 128,
      "num_attention_heads": 4, "num_key_value_heads": 2, "head_dim": 16,
      "vocab_size": 512, "mlp": "silu_glu"}
 SHAPES = {"max_batch": 4, "kv_len": 256, "chunk": 128}
 PK = {"bf16_flop_per_s": 1e12, "hbm_byte_per_s": 1e10}
+DENSE = spec.family(REPO, "dense")
 
 
 class _Req:
@@ -32,8 +34,8 @@ def _ctx(**trace):
                               "jit__packed_prefill_fn": [0.05, 1],
                               "jit__chunk_step_fn": [0.15, 1]},
                      kernels=trace.get("kernels", {}))
-    return readers.Context(m=M, shapes=SHAPES, pk=PK, run=run, calls=calls,
-                           trace=t)
+    return readers.Context(m=M, fam=DENSE, shapes=SHAPES, pk=PK, run=run,
+                           calls=calls, trace=t)
 
 
 def test_queue_wait_counts_the_unadmitted_with_their_whole_wait():
@@ -77,8 +79,9 @@ def test_decode_roofline_reads_the_kernel_in_its_program():
 
 def test_mfu_splits_by_program():
     c = _ctx()
-    dec = roofline.model_flops(M, [(9, 1), (19, 1), (10, 1), (20, 1)], 4)
-    pre = roofline.model_flops(M, [(0, 8), (0, 16), (128, 64)], 2)
+    dec = roofline.model_flops(DENSE, M, [(9, 1), (19, 1), (10, 1), (20, 1)],
+                               4)
+    pre = roofline.model_flops(DENSE, M, [(0, 8), (0, 16), (128, 64)], 2)
     assert readers.step_mfu_pct(c) == pytest.approx(dec / 0.2 / 1e12 * 100)
     assert readers.prefill_mfu_pct(c) == pytest.approx(
         pre / 0.2 / 1e12 * 100)
@@ -97,3 +100,63 @@ def test_prefill_roofline_finds_the_kernel_by_the_engine_chunk(chunk):
         least / 0.02 * 100)
     c.shapes = dict(SHAPES, chunk=chunk * 2)
     assert readers.prefill_attn_roofline(c) is None
+
+
+def _plane(i: int) -> str:
+    """One chip's plane: a 2 ms fused step from 0, a 1 ms packed prefill
+    from 3 ms, each one fusion; the decode kernel runs 0.5 ms in the
+    step."""
+    kern = "%k = custom-call(s32[4,1,256]{2,1,0} %p) tpu_custom_call"
+    return f'''planes {{ id: {i + 1} name: "/device:TPU:{i}"
+  lines {{ id: 1 name: "XLA Modules" timestamp_ns: 1000
+    events {{ metadata_id: 1 offset_ps: 0 duration_ps: 2000000000 }}
+    events {{ metadata_id: 2 offset_ps: 3000000000 duration_ps: 1000000000 }}
+  }}
+  lines {{ id: 2 name: "XLA Ops" timestamp_ns: 1000
+    events {{ metadata_id: 3 offset_ps: 0 duration_ps: 2000000000 }}
+    events {{ metadata_id: 4 offset_ps: 0 duration_ps: 500000000 }}
+    events {{ metadata_id: 5 offset_ps: 3000000000 duration_ps: 1000000000 }}
+  }}
+  event_metadata {{ key: 1 value {{ id: 1 name: "jit__fused_step_fn(1)" }} }}
+  event_metadata {{ key: 2 value {{ id: 2 name: "jit__packed_prefill_fn(2)" }} }}
+  event_metadata {{ key: 3 value {{ id: 3 name: "%fusion.1 = f32[2] fusion()" }} }}
+  event_metadata {{ key: 4 value {{ id: 4 name: "{kern.replace('"', "'")}" }} }}
+  event_metadata {{ key: 5 value {{ id: 5 name: "%fusion.2 = f32[2] fusion()" }} }}
+}}
+'''
+
+
+def _read_trace(tmp_path, chips: int):
+    from jax.profiler import ProfileData
+
+    text = "".join(_plane(i) for i in range(chips))
+    path = tmp_path / f"chips{chips}.xplane.pb"
+    path.write_bytes(ProfileData.text_proto_to_serialized_xspace(text))
+    return xplane.reduce(str(path))
+
+
+READERS = ["decode_step_ms", "prefill_ms_per_ktok", "idle_pct",
+           "step_mfu_pct", "prefill_mfu_pct", "slice_mfu_pct",
+           "decode_attn_roofline"]
+
+
+def test_readers_on_a_mesh_count_every_chip_once(tmp_path):
+    """Two chips that each run the one-chip trace's programs for as long:
+    a time per step or per token, and the idle share, read as on one
+    chip; a share of the peak or of a roofline reads half, since twice
+    the chips did the same work in the same time."""
+    one, two = _ctx(), _ctx()
+    one.trace, two.trace = _read_trace(tmp_path, 1), _read_trace(tmp_path, 2)
+    assert (one.trace.n_chips, two.trace.n_chips) == (1, 2)
+    assert two.trace.modules["jit__fused_step_fn"] == pytest.approx(
+        [0.004, 2])
+    a = {r: getattr(readers, r)(one) for r in READERS}
+    b = {r: getattr(readers, r)(two) for r in READERS}
+    assert a["decode_step_ms"] == pytest.approx(2.0)
+    assert a["prefill_ms_per_ktok"] == pytest.approx(1.0)
+    assert a["idle_pct"] == pytest.approx((1 - 0.003 / 2.0) * 100)
+    for r in ("decode_step_ms", "prefill_ms_per_ktok", "idle_pct"):
+        assert b[r] == pytest.approx(a[r]), r
+    for r in ("step_mfu_pct", "prefill_mfu_pct", "slice_mfu_pct",
+              "decode_attn_roofline"):
+        assert a[r] > 0 and b[r] == pytest.approx(a[r] / 2), r

@@ -2,7 +2,10 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from harness import roofline
+from conftest import REPO
+from harness import roofline, spec
+
+DENSE = spec.family(REPO, "dense")
 
 QWEN = {"num_hidden_layers": 36, "hidden_size": 2048,
         "intermediate_size": 11008, "num_attention_heads": 16,
@@ -29,8 +32,8 @@ def test_prefill_attention_counts_causal_pairs_only():
 def test_model_flops_per_token():
     per_layer = 2048 * (16 * 128 + 2 * 2 * 128) + 16 * 128 * 2048 \
         + 3 * 2048 * 11008
-    assert roofline.matmul_params_per_layer(QWEN) == per_layer
-    f = roofline.model_flops(QWEN, [(99, 1)], 1)   # one token at context 100
+    assert DENSE.matmul_params_per_layer(QWEN) == per_layer
+    f = roofline.model_flops(DENSE, QWEN, [(99, 1)], 1)  # one token at 100
     assert f == (2 * per_layer * 36 + 2 * 16 * 256 * 100 * 36
                  + 2 * 2048 * 151936)
 

@@ -79,6 +79,21 @@ def test_seeds_offer_the_same_sizes_in_another_order():
     assert ra[-1].t == pytest.approx(rb[-1].t)
 
 
+def test_a_mix_with_sizes_seed_offers_every_seed_the_same_sequence():
+    mix = dict(MIX, sizes_seed=0)
+    a = RequestStream(mix, 1, 151936, 2.0)
+    b = RequestStream(mix, 2 ** 33 + 5, 151936, 2.0)
+    ra = [a.pop() for _ in range(80)]
+    rb = [b.pop() for _ in range(80)]
+    assert [(r.t, r.max_new_tokens, r.prompt.size) for r in ra] == \
+        [(r.t, r.max_new_tokens, r.prompt.size) for r in rb]
+    assert not any(np.array_equal(x.prompt, y.prompt)
+                   for x, y in zip(ra, rb))          # the ids follow the seed
+    c = [RequestStream(MIX, 0, 151936, 2.0).pop() for _ in range(1)]
+    assert (c[0].t, c[0].max_new_tokens, c[0].prompt.size) == \
+        (ra[0].t, ra[0].max_new_tokens, ra[0].prompt.size)
+
+
 def test_closed_loop_needs_no_rate_and_open_loop_does():
     RequestStream(dict(MIX, loop="closed"), 1, 100).pop()
     with pytest.raises(ValueError):
